@@ -12,6 +12,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.core import (build_training_graph, edge_tpu, ga_checkpointing,
                         gpt2_graph, keepset_to_policy)
@@ -45,9 +46,8 @@ def main():
     policy = keepset_to_policy(chosen.keep)
 
     def block(w, x):
-        h = jax.ad_checkpoint.checkpoint_name(jnp.tanh(x @ w["w1"]),
-                                              "mlp_hidden")
-        o = jax.ad_checkpoint.checkpoint_name(h @ w["w2"], "attn_out")
+        h = checkpoint_name(jnp.tanh(x @ w["w1"]), "mlp_hidden")
+        o = checkpoint_name(h @ w["w2"], "attn_out")
         return o.sum()
 
     w = {"w1": jnp.ones((64, 64)), "w2": jnp.ones((64, 64))}

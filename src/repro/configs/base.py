@@ -84,11 +84,8 @@ class ModelConfig:
     scan_unroll: int = 1             # >1: unroll scans (roofline flop counting)
     seq_sharded_acts: bool = False   # SP: shard residual stream over 'model'
                                      # between blocks (saved scan carry /16)
-    sharded_embed: bool = False      # masked-gather embedding via the
-                                     # version-stable shard_map shim
-                                     # (repro.distributed.sharding.shard_map;
-                                     # jax.shard_map on new JAX, experimental
-                                     # path on old): measured ~neutral on
+    sharded_embed: bool = False      # masked-gather embedding via
+                                     # jax.shard_map: measured ~neutral on
                                      # peak mem (§Perf iteration 5,
                                      # hypothesis refuted) — keep XLA's
                                      # gather by default

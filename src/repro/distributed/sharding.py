@@ -160,16 +160,6 @@ def prune_pspec(shape: tuple, spec: P, mesh: Mesh) -> P:
     return P(*out)
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, **kw):
-    """Version-stable ``shard_map``: prefer the public ``jax.shard_map``
-    (JAX ≥ 0.6), fall back to the experimental module on older releases.
-    Keyword-only call style works across both signatures."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def shard(x, *logical_axes):
     """with_sharding_constraint by logical axes; no-op without a mesh;
     axes that don't divide the dim are dropped (replicated)."""

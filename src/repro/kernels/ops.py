@@ -2,6 +2,8 @@
 
 * auto-`interpret` on CPU (the kernels TARGET TPU; interpret mode executes
   the kernel body in Python for correctness validation);
+* block sizes are halved until they divide the tensor, and refused when
+  that leaves a block the TPU's (8, 128) tiling rule rejects;
 * `flash_attention` carries a custom_vjp wiring the recompute backward;
 * model-facing layouts (B,S,H,hd) are adapted to kernel layouts here.
 """
@@ -86,6 +88,18 @@ flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # ---------------------------------------------------------------------------
 
 
+def _divisor_block(n: int, block: int, align: int) -> int:
+    """Largest ``block / 2**k`` dividing ``n`` (``n`` itself when smaller).
+    The result must be a multiple of ``align`` or the whole of ``n``."""
+    b = min(block, n)
+    while n % b:
+        b //= 2
+    if b % align and b != n:
+        raise ValueError(f"no {align}-aligned block of at most {block} "
+                         f"divides {n} (largest divisor found: {b})")
+    return b
+
+
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows",
                                              "interpret"))
 def rmsnorm(x, scale, eps: float = 1e-6, block_rows: int = 256,
@@ -97,10 +111,8 @@ def rmsnorm(x, scale, eps: float = 1e-6, block_rows: int = 256,
     for s in shp[:-1]:
         rows *= s
     x2 = x.reshape(rows, shp[-1])
-    br = block_rows
-    while rows % br:
-        br //= 2
-    out = _rn.rmsnorm(x2, scale, eps=eps, block_rows=max(br, 1),
+    br = _divisor_block(rows, block_rows, 8)
+    out = _rn.rmsnorm(x2, scale, eps=eps, block_rows=br,
                       interpret=interpret)
     return out.reshape(shp)
 
@@ -111,12 +123,10 @@ def fused_adam(p, g, m, v, count, lr, b1=0.9, b2=0.95, eps=1e-8,
     interpret = _default_interpret() if interpret is None else interpret
     shp = p.shape
     n = p.size
-    block = 65536
-    while n % block:
-        block //= 2
+    block = _divisor_block(n, 65536, 128)
     out = _ad.fused_adam(p.reshape(n), g.reshape(n), m.reshape(n),
                          v.reshape(n), count, lr=lr, b1=b1, b2=b2, eps=eps,
-                         weight_decay=weight_decay, block=max(block, 1),
+                         weight_decay=weight_decay, block=block,
                          interpret=interpret)
     return tuple(t.reshape(shp) for t in out)
 

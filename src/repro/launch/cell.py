@@ -206,8 +206,6 @@ def lower_cell(arch: str, shape_name: str, mesh,
             res.compile_s = time.time() - t0
 
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):    # older JAX returns [dict]
-            ca = ca[0] if ca else {}
         res.flops = float(ca.get("flops", 0.0))
         res.hlo_bytes = float(ca.get("bytes accessed", 0.0))
         ma = compiled.memory_analysis()

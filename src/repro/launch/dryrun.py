@@ -1,8 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init).  512 host devices back the production meshes:
-# single-pod (16,16)=256 and multi-pod (2,16,16)=512.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (jax locks the platform
+# and device count at first init).  512 host devices back the production
+# meshes: single-pod (16,16)=256 and multi-pod (2,16,16)=512.  The dry run
+# is a CPU tool: pinning the platform keeps it (and the children that
+# benchmarks/roofline.py starts) off an accelerator another process holds.
 
 import argparse          # noqa: E402
 import json              # noqa: E402

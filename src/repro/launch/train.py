@@ -3,7 +3,8 @@
 Responsibilities beyond the jitted step:
   * checkpoint/restart (async writer, atomic commits, exact data resume);
   * failure handling — a failed step re-creates the mesh from surviving
-    devices (``best_mesh_for``) and restores the latest checkpoint;
+    devices (``best_mesh_for``) and restores the latest checkpoint; a step
+    that fails again after that restore re-raises;
   * straggler watchdog — steps exceeding ``straggler_factor ×`` the rolling
     median are logged and counted (on real pods this feeds the controller
     that evicts the slow host; here it guards CI);
@@ -17,6 +18,7 @@ Run (CPU example, tiny config):
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import statistics
 import time
@@ -32,6 +34,7 @@ from ..models.layers import abstract
 from ..models.transformer import init_params, param_axes, param_specs
 from ..optim.optimizers import make_optimizer, warmup_cosine
 from ..training.train_step import make_train_step
+from .compile_cache import enable_compile_cache
 from .mesh import best_mesh_for, make_mesh
 
 
@@ -75,19 +78,22 @@ class Trainer:
                 self.step_jit = jax.jit(step_fn,
                                         in_shardings=(self.p_sh, self.o_sh,
                                                       b_sh, None),
+                                        out_shardings=(self.p_sh, self.o_sh,
+                                                       None),
                                         donate_argnums=(0, 1))
         else:
             self.p_sh = self.o_sh = None
             self.step_jit = jax.jit(step_fn, donate_argnums=(0, 1))
 
     def init_state(self):
+        """Parameters and optimizer state, each made where it lives: under
+        a mesh every device builds only its own shards."""
         with use_mesh(self.mesh):
-            params = init_params(self.cfg, jax.random.PRNGKey(self.seed))
-            if self.p_sh is not None:
-                params = jax.tree.map(jax.device_put, params, self.p_sh)
-            opt_state = self.opt.init(params)
-            if self.o_sh is not None:
-                opt_state = jax.tree.map(jax.device_put, opt_state, self.o_sh)
+            params = jax.jit(functools.partial(init_params, self.cfg),
+                             out_shardings=self.p_sh)(
+                jax.random.PRNGKey(self.seed))
+            opt_state = jax.jit(self.opt.init,
+                                out_shardings=self.o_sh)(params)
         return params, opt_state
 
     # -- restore -------------------------------------------------------------
@@ -118,6 +124,7 @@ class Trainer:
         logs: list[dict] = []
         log_f = open(log_path, "a") if log_path else None
         step = start
+        failed_step = None
         while step < steps:
             batch = next(data)
             t0 = time.time()
@@ -129,10 +136,11 @@ class Trainer:
                     params, opt_state, metrics = self.step_jit(
                         params, opt_state, batch, jnp.int32(step))
                     metrics = jax.tree.map(float, jax.device_get(metrics))
-            except Exception as e:  # noqa: BLE001 — node failure path
+            except Exception:  # noqa: BLE001 — node failure path
                 self.failures += 1
-                if self.ckpt is None:
+                if self.ckpt is None or step == failed_step:
                     raise
+                failed_step = step
                 # re-create mesh from surviving devices + restore
                 self.ckpt.wait()
                 if self.mesh is not None:
@@ -186,6 +194,7 @@ def main() -> None:
                     help="none | dxm (e.g. 2x4) using host devices")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     shape = get_shape(args.shape)
     mesh = None

@@ -69,8 +69,10 @@ def gqa_attention(p, x, cfg, positions, window: int | None = None):
     q, k, v = _qkv(p, x, cfg, positions)
     q = q.reshape(B, S, Kv, G, hd)
 
-    if cfg.use_flash and S % 128 == 0:
+    if cfg.use_flash:
         # Pallas TPU kernel (kernels/flash_attention); interpret-mode on CPU
+        if S % 128:
+            raise ValueError(f"use_flash needs seq_len % 128 == 0, got {S}")
         from ..kernels.ops import flash_attention as _flash
         qh = q.reshape(B, S, H, hd)
         ctx = _flash(qh, k, v, True, window).reshape(B, S, Kv, G, hd)

@@ -108,8 +108,7 @@ def embed_lookup(table, tokens, enabled: bool = True):
     from its local vocab slice with out-of-range rows masked to zero and the
     partials are psum'ed — no replicated intermediate ever exists.
     """
-    from ..distributed.sharding import (current_mesh, prune_pspec,
-                                        shard_map)
+    from ..distributed.sharding import current_mesh, prune_pspec
     from jax.sharding import PartitionSpec as P
 
     mesh = current_mesh()
@@ -135,8 +134,8 @@ def embed_lookup(table, tokens, enabled: bool = True):
         x = jnp.where(ok, x, jnp.zeros((), x.dtype))
         return jax.lax.psum(x, "model")
 
-    return shard_map(body, mesh=mesh, in_specs=(tbl_spec, tok_spec),
-                     out_specs=out_spec)(table, tokens)
+    return jax.shard_map(body, mesh=mesh, in_specs=(tbl_spec, tok_spec),
+                         out_specs=out_spec)(table, tokens)
 
 
 # -- MLP variants ------------------------------------------------------------

@@ -123,6 +123,32 @@ def test_fused_adam_matches_optimizer():
                                atol=1e-6)
 
 
+@pytest.mark.parametrize("kernel,n", [("rmsnorm", 300),
+                                      ("fused_adam", 65536 + 64)])
+def test_unaligned_block_refused(kernel, n):
+    """Block halving that ends off the (8, 128) tiling raises instead of
+    shrinking the block to a size the TPU compiler refuses."""
+    x = jnp.ones((n, 64) if kernel == "rmsnorm" else (n,), jnp.float32)
+    with pytest.raises(ValueError, match="aligned block"):
+        if kernel == "rmsnorm":
+            ops.rmsnorm(x, jnp.zeros((64,), jnp.float32))
+        else:
+            ops.fused_adam(x, x, x, x, jnp.int32(1), lr=1e-3)
+
+
+def test_model_flash_path_rejects_unaligned_seq():
+    from dataclasses import replace
+    from repro.configs import smoke_config
+    from repro.models.attention import attn_specs, gqa_attention
+    from repro.models.layers import materialize
+    cfg = replace(smoke_config("phi3-medium-14b"), use_flash=True)
+    p = materialize(attn_specs(cfg), jax.random.PRNGKey(0))
+    x = jnp.ones((1, 96, cfg.d_model), jnp.bfloat16)
+    pos = jnp.arange(96, dtype=jnp.int32)[None]
+    with pytest.raises(ValueError, match="seq_len % 128"):
+        gqa_attention(p, x, cfg, pos)
+
+
 def test_model_flash_path_matches_dense():
     """cfg.use_flash=True (kernel) ≡ dense attention inside the real model."""
     from dataclasses import replace

@@ -4,6 +4,7 @@ jax.checkpoint policy) plus the claims the paper makes about it."""
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 import numpy as np
 
 from repro.core import (FusionConfig, build_training_graph, edge_tpu,
@@ -88,7 +89,7 @@ def test_monet_decision_drives_real_jax_step():
     assert policy is not None
 
     def block(w, x):
-        h = jax.ad_checkpoint.checkpoint_name(jnp.tanh(x @ w), "mlp_hidden")
+        h = checkpoint_name(jnp.tanh(x @ w), "mlp_hidden")
         return h @ w.T
 
     w = jnp.ones((16, 16))

@@ -69,6 +69,53 @@ def test_straggler_watchdog(cfg):
     assert tr.stragglers > 0            # every step flagged at factor 0
 
 
+def test_repeated_failure_after_restore_raises(cfg, tmp_path, monkeypatch):
+    """A step that fails again after the restore re-raises instead of
+    restoring forever."""
+    import repro.launch.train as train_mod
+
+    def make_failing_step(*a, **k):
+        def step(*args):
+            raise RuntimeError("step always fails")
+        return step
+
+    monkeypatch.setattr(train_mod, "make_train_step", make_failing_step)
+    tr = Trainer(cfg, SHAPE, ckpt_dir=str(tmp_path / "ck"))
+    with pytest.raises(RuntimeError, match="always fails"):
+        tr.fit(4)
+    assert tr.failures == 2
+
+
+def test_trainer_on_mesh_matches_no_mesh(cfg):
+    """The mesh path (state built sharded under jit, pinned step shardings)
+    trains like the single-device path."""
+    from repro.launch.mesh import make_mesh
+    plain = [m["loss"] for m in Trainer(cfg, SHAPE, lr=1e-3).fit(3)]
+    tr = Trainer(cfg, SHAPE, make_mesh((1, 1), ("data", "model")), lr=1e-3)
+    meshed = [m["loss"] for m in tr.fit(3)]
+    np.testing.assert_allclose(meshed, plain, rtol=1e-5)
+    params, opt_state = tr._last_state
+    for leaf, sh in zip(jax.tree.leaves(params), jax.tree.leaves(tr.p_sh),
+                        strict=True):
+        assert leaf.sharding == sh
+
+
+def test_compile_cache_location(monkeypatch):
+    from repro.launch import compile_cache as cc
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/set/by/env")
+    assert cc.enable_compile_cache() == "/set/by/env"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert cc.enable_compile_cache() == str(cc.CHECKOUT_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(cc.CHECKOUT_CACHE)
+        assert cc.CHECKOUT_CACHE.parent == \
+            cc.Path(__file__).resolve().parents[1]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 # -- checkpoint store ----------------------------------------------------------
 
 
