@@ -84,6 +84,7 @@ def train_phase(cfg, shape, steps: int) -> list[float]:
     for m in logs:
         say(f"train step {m['step']} loss {m['loss']!r} "
             f"time_s {m['time_s']!r}")
+    # time_s is each step's full period (batch, dispatch, sync, log row)
     times = [m["time_s"] for m in logs]
     med = statistics.median(times[1:])
     say(f"train first_step_s {times[0]!r} (compile + run), "

@@ -6,9 +6,24 @@ Responsibilities beyond the jitted step:
     devices (``best_mesh_for``) and restores the latest checkpoint; a step
     that fails again after that restore re-raises;
   * straggler watchdog — steps exceeding ``straggler_factor ×`` the rolling
-    median are logged and counted (on real pods this feeds the controller
+    median of the last 20 periods (``time_s``, across the fits of one
+    ``Trainer``) are logged and counted (on real pods this feeds the controller
     that evicts the slow host; here it guards CI);
-  * metrics logging (JSONL).
+  * metrics logging (JSONL): a row per step, whose ``time_s`` is the step's
+    full period on the span recorder's clock (end of ``fit.sync`` to end of
+    ``fit.sync``; the first from the start of ``fit.data``);
+  * spans and compile counters (``launch/spans.py``, always on): ``fit``
+    opens one record; each loop iteration runs under a
+    ``StepTraceAnnotation("train")`` and is cut into the spans
+    ``fit.data`` (``next(data)``: the batch and its copy to the device),
+    ``fit.step`` (the call of the jitted step), ``fit.sync`` (``device_get``
+    of the metrics), ``fit.log`` (straggler check, log row, JSONL write),
+    ``fit.ckpt`` (``ckpt.save``'s snapshot) and ``fit.recover`` (the
+    failure path); ``fit.restore`` wraps ``restore_or_init``.  Backend
+    compiles (persistent-cache loads included) are counted against the
+    innermost open span; compile seconds are summed for the process.  No
+    span is called ``dispatch`` or ``window``: those names belong to the
+    benchmark harness's own spans.
 
 Run (CPU example, tiny config):
   PYTHONPATH=src python -m repro.launch.train --arch gemma3-1b --smoke \
@@ -18,10 +33,10 @@ Run (CPU example, tiny config):
 from __future__ import annotations
 
 import argparse
+import collections
 import functools
 import json
 import statistics
-import time
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +51,7 @@ from ..optim.optimizers import make_optimizer, warmup_cosine
 from ..training.train_step import make_train_step
 from .compile_cache import enable_compile_cache
 from .mesh import best_mesh_for, make_mesh
+from . import spans
 
 
 class Trainer:
@@ -52,7 +68,7 @@ class Trainer:
         self.ckpt_every = ckpt_every
         self.seed = seed
         self.straggler_factor = straggler_factor
-        self.step_times: list[float] = []
+        self.periods = collections.deque(maxlen=20)  # the watchdog's window
         self.stragglers = 0
         self.failures = 0
         self.ckpt = AsyncCheckpointer(ckpt_dir) if ckpt_dir else None
@@ -116,64 +132,87 @@ class Trainer:
     def fit(self, steps: int, batch_override: int | None = None,
             seq_override: int | None = None, log_path: str | None = None,
             inject_failure_at: int | None = None) -> list[dict]:
-        params, opt_state, start = self.restore_or_init()
-        data = SyntheticDataset(self.cfg, self.shape, seed=self.seed,
-                                start_step=start,
+        with spans.fit() as rec:
+            with spans.span("fit.restore"):
+                params, opt_state, start = self.restore_or_init()
+            data = SyntheticDataset(self.cfg, self.shape, seed=self.seed,
+                                    start_step=start,
+                                    batch_override=batch_override,
+                                    seq_override=seq_override)
+            logs: list[dict] = []
+            log_f = open(log_path, "a") if log_path else None
+            step = start
+            failed_step = None
+            last_ns = None      # where the current step's period began
+            while step < steps:
+                with spans.step(step):
+                    with spans.span("fit.data") as fetched:
+                        batch = next(data)
+                    if last_ns is None:
+                        last_ns = fetched.start_ns
+                    try:
+                        with spans.span("fit.step"):
+                            if inject_failure_at is not None and \
+                                    step == inject_failure_at:
+                                inject_failure_at = None
+                                raise RuntimeError("injected node failure")
+                            with use_mesh(self.mesh):
+                                params, opt_state, metrics = self.step_jit(
+                                    params, opt_state, batch, jnp.int32(step))
+                        with spans.span("fit.sync") as synced:
+                            metrics = jax.tree.map(float,
+                                                   jax.device_get(metrics))
+                    except Exception:  # noqa: BLE001 — node failure path
+                        self.failures += 1
+                        if self.ckpt is None or step == failed_step:
+                            raise
+                        failed_step = step
+                        with spans.span("fit.recover"):
+                            # re-create mesh from surviving devices + restore
+                            self.ckpt.wait()
+                            if self.mesh is not None:
+                                n = len(jax.devices())
+                                self.mesh = best_mesh_for(n)
+                            self._build()
+                            with spans.span("fit.restore"):
+                                params, opt_state, start_r = \
+                                    self.restore_or_init()
+                            data = SyntheticDataset.from_state(
+                                self.cfg, self.shape,
+                                {"step": start_r, "seed": self.seed},
                                 batch_override=batch_override,
                                 seq_override=seq_override)
-        logs: list[dict] = []
-        log_f = open(log_path, "a") if log_path else None
-        step = start
-        failed_step = None
-        while step < steps:
-            batch = next(data)
-            t0 = time.time()
-            try:
-                if inject_failure_at is not None and step == inject_failure_at:
-                    inject_failure_at = None
-                    raise RuntimeError("injected node failure")
-                with use_mesh(self.mesh):
-                    params, opt_state, metrics = self.step_jit(
-                        params, opt_state, batch, jnp.int32(step))
-                    metrics = jax.tree.map(float, jax.device_get(metrics))
-            except Exception:  # noqa: BLE001 — node failure path
-                self.failures += 1
-                if self.ckpt is None or step == failed_step:
-                    raise
-                failed_step = step
-                # re-create mesh from surviving devices + restore
+                        step = start_r
+                        last_ns = None
+                        continue
+                    with spans.span("fit.log"):
+                        dt = (synced.end_ns - last_ns) / 1e9
+                        last_ns = synced.end_ns
+                        rec.steps += 1
+                        self.periods.append(dt)
+                        med = statistics.median(self.periods)
+                        if len(self.periods) > 5 and \
+                                dt > self.straggler_factor * med:
+                            self.stragglers += 1
+                            metrics["straggler"] = dt / med
+                        metrics.update(step=step, time_s=dt)
+                        logs.append(metrics)
+                        if log_f:
+                            log_f.write(json.dumps(metrics) + "\n")
+                            log_f.flush()
+                    step += 1
+                    if self.ckpt and (step % self.ckpt_every == 0
+                                      or step == steps):
+                        with spans.span("fit.ckpt"):
+                            self.ckpt.save(
+                                step, {"params": params, "opt": opt_state},
+                                extra={"arch": self.cfg.name})
+            if self.ckpt:
                 self.ckpt.wait()
-                if self.mesh is not None:
-                    n = len(jax.devices())
-                    self.mesh = best_mesh_for(n)
-                self._build()
-                params, opt_state, start_r = self.restore_or_init()
-                data = SyntheticDataset.from_state(
-                    self.cfg, self.shape, {"step": start_r, "seed": self.seed},
-                    batch_override=batch_override, seq_override=seq_override)
-                step = start_r
-                continue
-            dt = time.time() - t0
-            self.step_times.append(dt)
-            med = statistics.median(self.step_times[-20:])
-            if len(self.step_times) > 5 and dt > self.straggler_factor * med:
-                self.stragglers += 1
-                metrics["straggler"] = dt / med
-            metrics.update(step=step, time_s=dt)
-            logs.append(metrics)
             if log_f:
-                log_f.write(json.dumps(metrics) + "\n")
-                log_f.flush()
-            step += 1
-            if self.ckpt and (step % self.ckpt_every == 0 or step == steps):
-                self.ckpt.save(step, {"params": params, "opt": opt_state},
-                               extra={"arch": self.cfg.name})
-        if self.ckpt:
-            self.ckpt.wait()
-        if log_f:
-            log_f.close()
-        self._last_state = (params, opt_state)
-        return logs
+                log_f.close()
+            self._last_state = (params, opt_state)
+            return logs
 
 
 def main() -> None:

@@ -92,25 +92,35 @@ def param_axes(cfg):
 
 
 def _apply_layer(prm, x, cfg, spec, positions):
-    h = rmsnorm(x, prm["ln1"]["scale"], cfg.norm_eps)
-    h = checkpoint_name(h, "attn_in")
-    if spec.mixer == "attn":
-        mix = gqa_attention(prm["attn"], h, cfg, positions, window=None)
-    elif spec.mixer == "local":
-        mix = gqa_attention(prm["attn"], h, cfg, positions, window=cfg.window)
-    elif spec.mixer == "mla":
-        mix = mla_attention(prm["attn"], h, cfg, positions)
-    else:
-        mix = ssd_apply(prm["mixer"], h, cfg)
-    x = x + mix
+    # named scopes: a profiler trace and the HLO's op_name carry them
+    with jax.named_scope("mixer"):
+        h = rmsnorm(x, prm["ln1"]["scale"], cfg.norm_eps)
+        h = checkpoint_name(h, "attn_in")
+        if spec.mixer == "attn":
+            with jax.named_scope("attn"):
+                mix = gqa_attention(prm["attn"], h, cfg, positions,
+                                    window=None)
+        elif spec.mixer == "local":
+            with jax.named_scope("attn"):
+                mix = gqa_attention(prm["attn"], h, cfg, positions,
+                                    window=cfg.window)
+        elif spec.mixer == "mla":
+            with jax.named_scope("mla"):
+                mix = mla_attention(prm["attn"], h, cfg, positions)
+        else:
+            with jax.named_scope("ssd"):
+                mix = ssd_apply(prm["mixer"], h, cfg)
+        x = x + mix
     aux = jnp.zeros((), jnp.float32)
     if spec.moe:
-        h2 = rmsnorm(x, prm["ln2"]["scale"], cfg.norm_eps)
-        y, aux = moe_apply(prm["moe"], h2, cfg)
-        x = x + y
+        with jax.named_scope("moe"):
+            h2 = rmsnorm(x, prm["ln2"]["scale"], cfg.norm_eps)
+            y, aux = moe_apply(prm["moe"], h2, cfg)
+            x = x + y
     elif cfg.mlp != "none":
-        h2 = rmsnorm(x, prm["ln2"]["scale"], cfg.norm_eps)
-        x = x + mlp_apply(prm["mlp"], h2, cfg)
+        with jax.named_scope("mlp"):
+            h2 = rmsnorm(x, prm["ln2"]["scale"], cfg.norm_eps)
+            x = x + mlp_apply(prm["mlp"], h2, cfg)
     x = checkpoint_name(x, "block_out")
     seq_ax = "seq_sp" if cfg.seq_sharded_acts else "seq"
     return shard(x, "batch", seq_ax, "embed_act"), aux
@@ -123,12 +133,13 @@ def forward_hidden(params, cfg, inputs, positions=None):
     period = cfg.scan_period()
     n_full = cfg.n_layers // period
 
-    if cfg.input_mode == "tokens":
-        x = embed_lookup(params["embed"]["table"], inputs,
-                         enabled=cfg.sharded_embed)
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-    else:
-        x = inputs.astype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        if cfg.input_mode == "tokens":
+            x = embed_lookup(params["embed"]["table"], inputs,
+                             enabled=cfg.sharded_embed)
+            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        else:
+            x = inputs.astype(cfg.compute_dtype)
     B, S = x.shape[0], x.shape[1]
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))

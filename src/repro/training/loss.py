@@ -33,42 +33,43 @@ def _xent_from_logits(logits, labels):
 def lm_loss(params, cfg, inputs, labels, loss_chunk: int | None = None):
     """Returns (loss, metrics).  labels: (B,S) int32, -1 = masked."""
     hidden, aux = forward_hidden(params, cfg, inputs)
-    w = unembed_weight(params, cfg)
-    B, S, D = hidden.shape
-    mask = (labels >= 0).astype(jnp.float32)
-    safe_labels = jnp.maximum(labels, 0)
+    with jax.named_scope("loss"):
+        w = unembed_weight(params, cfg)
+        B, S, D = hidden.shape
+        mask = (labels >= 0).astype(jnp.float32)
+        safe_labels = jnp.maximum(labels, 0)
 
-    chunk = loss_chunk if loss_chunk is not None else cfg.loss_chunk
-    if chunk == 0:  # auto: chunk when the logits tensor would be > 2^28 elems
-        chunk = S // 8 if S * cfg.vocab > (1 << 28) and S % 8 == 0 else 0
+        chunk = loss_chunk if loss_chunk is not None else cfg.loss_chunk
+        if chunk == 0:  # auto: chunk when the logits tensor would be > 2^28 elems
+            chunk = S // 8 if S * cfg.vocab > (1 << 28) and S % 8 == 0 else 0
 
-    if chunk and S % chunk == 0 and S > chunk:
-        nc = S // chunk
-        hc = hidden.reshape(B, nc, chunk, D).transpose(1, 0, 2, 3)
-        lc = safe_labels.reshape(B, nc, chunk).transpose(1, 0, 2)
-        mc = mask.reshape(B, nc, chunk).transpose(1, 0, 2)
+        if chunk and S % chunk == 0 and S > chunk:
+            nc = S // chunk
+            hc = hidden.reshape(B, nc, chunk, D).transpose(1, 0, 2, 3)
+            lc = safe_labels.reshape(B, nc, chunk).transpose(1, 0, 2)
+            mc = mask.reshape(B, nc, chunk).transpose(1, 0, 2)
 
-        @jax.checkpoint   # recompute chunk logits in bwd: never keep (B,c,V)
-        def chunk_nll(h, lab, msk):
-            logits = h @ w                       # (B, chunk, V) transient
+            @jax.checkpoint   # recompute chunk logits in bwd: never keep (B,c,V)
+            def chunk_nll(h, lab, msk):
+                logits = h @ w                       # (B, chunk, V) transient
+                logits = shard(logits, "batch", "seq", "vocab")
+                nll, z = _xent_from_logits(logits, lab)
+                return jnp.sum(nll * msk), jnp.sum(z * msk)
+
+            def body(carry, xs):
+                nll_sum, z_sum = carry
+                dn, dz = chunk_nll(*xs)
+                return (nll_sum + dn, z_sum + dz), None
+
+            (nll_sum, z_sum), _ = jax.lax.scan(
+                body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
+                (hc, lc, mc), unroll=min(cfg.scan_unroll, nc))
+        else:
+            logits = hidden @ w
             logits = shard(logits, "batch", "seq", "vocab")
-            nll, z = _xent_from_logits(logits, lab)
-            return jnp.sum(nll * msk), jnp.sum(z * msk)
-
-        def body(carry, xs):
-            nll_sum, z_sum = carry
-            dn, dz = chunk_nll(*xs)
-            return (nll_sum + dn, z_sum + dz), None
-
-        (nll_sum, z_sum), _ = jax.lax.scan(
-            body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-            (hc, lc, mc), unroll=min(cfg.scan_unroll, nc))
-    else:
-        logits = hidden @ w
-        logits = shard(logits, "batch", "seq", "vocab")
-        nll, z = _xent_from_logits(logits, safe_labels)
-        nll_sum = jnp.sum(nll * mask)
-        z_sum = jnp.sum(z * mask)
+            nll, z = _xent_from_logits(logits, safe_labels)
+            nll_sum = jnp.sum(nll * mask)
+            z_sum = jnp.sum(z * mask)
 
     denom = jnp.maximum(mask.sum(), 1.0)
     nll_mean = nll_sum / denom

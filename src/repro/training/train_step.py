@@ -54,9 +54,11 @@ def make_train_step(cfg, optimizer: Optimizer, grad_accum: int = 1,
             loss = loss_sum / grad_accum
             metrics = {"loss": loss}
 
-        grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        new_params, new_opt_state = optimizer.update(grads, opt_state,
-                                                     params, step)
+        with jax.named_scope("clip"):
+            grads, gnorm = clip_by_global_norm(grads, clip_norm)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt_state = optimizer.update(grads, opt_state,
+                                                         params, step)
         metrics = dict(metrics)
         metrics["grad_norm"] = gnorm
         metrics["lr_step"] = jnp.asarray(step, jnp.int32)

@@ -1,6 +1,7 @@
-"""Compile-only guards for the TPU: every Pallas kernel at real widths and
-the whole gemma3-1b train step, compiled for one chip of a described
-``v5e:2x2`` topology (no chip attached).  Interpret-mode tests cannot see
+"""Compile-only guards for the TPU: every Pallas kernel at real widths, the
+whole gemma3-1b train step, and the MiniCPM3 smoke step with and without
+its named scopes, compiled for one chip of a described ``v5e:2x2``
+topology (no chip attached).  Interpret-mode tests cannot see
 what this catches: block tilings the chip refuses, operations Mosaic cannot
 lower, programs that do not fit the chip's memory.
 
@@ -9,8 +10,10 @@ process may load the TPU compiler library at a time, and every worker of a
 parallel run imports this file.  Keep these tests in this one file.
 """
 
+import contextlib
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -125,3 +128,46 @@ def test_gemma3_train_step_compiles(one_chip, monkeypatch, use_flash):
                 for x in jax.tree.leaves((aparams, aopt)))
     assert mem.alias_size_in_bytes >= state     # params + moments donated
     assert ("tpu_custom_call" in compiled.as_text()) == use_flash
+
+
+# debug information only: op_name and source lines, and the tables of files,
+# functions and stack frames they point into
+_METADATA = re.compile(r',?\s*metadata=\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\}')
+_DEBUG_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+
+
+def _without_debug_info(hlo: str) -> str:
+    return "\n\n".join(block for block in _METADATA.sub("", hlo).split("\n\n")
+                        if block.split("\n", 1)[0] not in _DEBUG_TABLES)
+
+
+def test_named_scopes_add_no_operations(one_chip, monkeypatch):
+    """The MiniCPM3 step (smoke widths), compiled for the chip with the
+    model's named scopes and with ``jax.named_scope`` a no-op: the optimized
+    HLO is the same once debug information is stripped."""
+    from repro.configs import smoke_config
+    from repro.data.pipeline import input_specs
+    from repro.launch.train import Trainer
+    from repro.models.transformer import abstract_params
+
+    cfg = smoke_config("minicpm3-4b")
+    shape = ShapeConfig("chip_compile", 256, 2, "train")
+
+    def put(tree):
+        return jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+
+    def optimized_hlo():
+        tr = Trainer(cfg, shape)
+        aparams = put(abstract_params(cfg))
+        return tr.step_jit.lower(
+            aparams, put(jax.eval_shape(tr.opt.init, aparams)),
+            put(input_specs(cfg, shape)),
+            _sds(one_chip, (), jnp.int32)).compile().as_text()
+
+    scoped = optimized_hlo()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = optimized_hlo()
+    assert re.search(r'op_name="[^"]*/mla/', scoped)
+    assert not re.search(r'op_name="[^"]*/mla/', plain)
+    assert _without_debug_info(scoped) == _without_debug_info(plain)

@@ -13,6 +13,7 @@ from repro.ckpt.store import (AsyncCheckpointer, available_steps,
                               save_checkpoint)
 from repro.configs import smoke_config
 from repro.configs.base import ShapeConfig
+from repro.launch import spans
 from repro.launch.train import Trainer
 from repro.optim.optimizers import (adafactor, adamw, clip_by_global_norm,
                                     galore_adamw, global_norm, sgd_momentum,
@@ -61,6 +62,9 @@ def test_failure_injection_recovers(cfg, tmp_path):
     # steps 4..6 re-run after restore from the step-4 checkpoint
     steps = [l["step"] for l in logs]
     assert steps.count(5) >= 1
+    # the failed step's span closed; the failure path is recorded
+    assert [s.name for s in spans.fits()[-1].spans if s.step == 6][:4] == [
+        "fit.data", "fit.step", "fit.restore", "fit.recover"]
 
 
 def test_straggler_watchdog(cfg):
