@@ -77,7 +77,11 @@ class ModelConfig:
     compute_dtype: str = "bfloat16"
     state_dtype: str = "float32"     # optimizer states (bf16 for ≥100B archs)
     remat: str = "dots"              # remat policy name (see core.remat_policy)
-    use_flash: bool = False          # Pallas kernels (TPU target only)
+    # Pallas flash attention (interpret mode off a TPU; under a mesh inside
+    # a shard_map).  It needs seq_len % 128 == 0: GQA raises otherwise, MLA
+    # keeps its materialised scores (its registry entry sets the flag, and
+    # its smoke runs use short sequences).
+    use_flash: bool = False
     attn_chunked: bool = False       # jnp flash-style chunked attention
     attn_chunk: int = 1024
     loss_chunk: int = 0              # 0 = auto (chunk when vocab*seq is large)

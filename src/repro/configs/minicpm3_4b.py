@@ -8,6 +8,6 @@ CONFIG = ModelConfig(
     d_ff=6400, vocab=73448, mlp="swiglu", pattern=("mla",),
     mla=MLAConfig(q_lora_rank=768, kv_lora_rank=256, qk_nope_dim=64,
                   qk_rope_dim=32, v_head_dim=64),
-    attn_chunked=True, remat="dots",
+    attn_chunked=True, remat="dots", use_flash=True,
     notes="MLA: cache is the 288-dim latent (c_kv + k_rope), not full KV",
 )

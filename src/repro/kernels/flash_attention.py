@@ -10,6 +10,14 @@ the kv loop is a ``fori_loop`` over ``block_k`` tiles with causality-pruned
 trip count.  GQA is handled by the BlockSpec index map (q-head i reads
 kv-head i//G) — no repeated K/V in HBM.
 
+q and k share one head dim; v may have its own (MLA: qk 96, v 64).  The
+output and dv take v's, dq and dk q's; the default scale is 1/√(q's).
+
+The MXU is fed in the inputs' own dtype with f32 accumulation: for bf16
+inputs the probabilities p and their gradient ds are cast to bf16 before
+their products, while scores, softmax statistics and accumulators stay f32.
+f32 inputs keep f32 operands.
+
 Backward is the standard two-kernel recompute scheme (dq then dk/dv) using
 the saved per-row logsumexp.
 
@@ -45,18 +53,31 @@ def _dot_tn(a, b):
                                preferred_element_type=jnp.float32)
 
 
-def _mask(q0, k0, shape, causal, window, q_offset):
-    """Visibility of keys ``k0 + j`` to queries ``q0 + i`` for an (i, j)
-    tile of ``shape``; None when everything is visible."""
-    qi = q0 + q_offset + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    ki = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+def _dot(a, b):
+    """a @ b with fp32 accumulation."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _masked(s, q0, k0, causal, window, q_offset):
+    """Scores ``s`` of queries ``q0 + i`` against keys ``k0 + j``, with
+    NEG_INF where the key is hidden from the query."""
+    qi = q0 + q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    ki = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     m = None
     if causal:
         m = ki <= qi
     if window is not None:
         w = qi - ki < window
         m = w if m is None else (m & w)
-    return m
+    return s if m is None else jnp.where(m, s, NEG_INF)
+
+
+def _kv_tiles(q0, bq, nk, bk, causal, q_offset):
+    """Key tiles ``[0, n)`` hold every key the query rows ``q0 .. q0 + bq``
+    can see; causality prunes those past the last row."""
+    if not causal:
+        return nk
+    return jnp.minimum(nk, pl.cdiv(q0 + bq + q_offset, bk))
 
 
 def _lanes(x):
@@ -71,39 +92,29 @@ def _lanes(x):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
                 window, block_k, q_offset):
-    bq, hd = q_ref.shape[1], q_ref.shape[2]
+    bq, hv = q_ref.shape[1], v_ref.shape[2]
     T = k_ref.shape[1]
-    q = q_ref[0].astype(jnp.float32) * scale          # (bq, hd)
+    q = q_ref[0]                                       # (bq, hd)
     q0 = pl.program_id(1) * bq
-
-    nk = T // block_k
-    if causal:
-        # causality prunes kv blocks beyond the last query row
-        last_q = q0 + bq + q_offset
-        nk_eff = jnp.minimum(nk, pl.cdiv(last_q, block_k))
-    else:
-        nk_eff = nk
+    nk = _kv_tiles(q0, bq, T // block_k, block_k, causal, q_offset)
 
     def body(i, carry):
-        m, l, acc = carry                              # (bq,1) (bq,1) (bq,hd)
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = _dot_nt(q, kb)                             # (bq, bk)
-        msk = _mask(q0, i * block_k, s.shape, causal, window, q_offset)
-        if msk is not None:
-            s = jnp.where(msk, s, NEG_INF)
+        m, l, acc = carry                              # (bq,1) (bq,1) (bq,hv)
+        kb = k_ref[0, pl.ds(i * block_k, block_k), :]
+        vb = v_ref[0, pl.ds(i * block_k, block_k), :]
+        s = _masked(_dot_nt(q, kb) * scale, q0, i * block_k, causal, window,
+                    q_offset)                          # (bq, bk) f32
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(axis=-1, keepdims=True)
-        acc_new = acc * corr + jnp.dot(p, vb,
-                                       preferred_element_type=jnp.float32)
+        acc_new = acc * corr + _dot(p.astype(vb.dtype), vb)
         return m_new, l_new, acc_new
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
-    a0 = jnp.zeros((bq, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nk_eff, body, (m0, l0, a0))
+    a0 = jnp.zeros((bq, hv), jnp.float32)
+    m, l, acc = jax.lax.fori_loop(0, nk, body, (m0, l0, a0))
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (bq, LANES))
@@ -111,11 +122,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
 
 def flash_attention_fwd(q, k, v, *, causal=True, window=None, scale=None,
                         block_q=128, block_k=128, interpret=False):
-    """q: (BH, S, hd); k/v: (BKv, T, hd); G = BH // BKv per batch-head
-    grouping must already be arranged so q row i maps to kv row i // G.
-    Returns (o, lse) with lse: (BH, S) fp32."""
+    """q: (BH, S, hd); k: (BKv, T, hd); v: (BKv, T, hv).  G = BH // BKv per
+    batch-head grouping must already be arranged so q row i maps to kv row
+    i // G.  Returns (o, lse) with o: (BH, S, hv), lse: (BH, S) fp32."""
     BH, S, hd = q.shape
     BKv, T, _ = k.shape
+    hv = v.shape[2]
     G = BH // BKv
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     block_q = min(block_q, S)
@@ -132,14 +144,14 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, scale=None,
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, T, hd), lambda i, j: (i // G, 0, 0)),
-            pl.BlockSpec((1, T, hd), lambda i, j: (i // G, 0, 0)),
+            pl.BlockSpec((1, T, hv), lambda i, j: (i // G, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, hd), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, hv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, block_q, LANES), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, S, hd), q.dtype),
+            jax.ShapeDtypeStruct((BH, S, hv), q.dtype),
             jax.ShapeDtypeStruct((BH, S, LANES), jnp.float32),
         ],
         interpret=interpret,
@@ -156,69 +168,55 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
                scale, causal, window, block_k, q_offset):
     bq, hd = q_ref.shape[1], q_ref.shape[2]
     T = k_ref.shape[1]
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+    q = q_ref[0]
+    do = do_ref[0]
     lse = lse_ref[0][:, :1]                           # (bq, 1)
     delta = delta_ref[0][:, :1]
     q0 = pl.program_id(1) * bq
-    nk = T // block_k
-    if causal:
-        last_q = q0 + bq + q_offset
-        nk_eff = jnp.minimum(nk, pl.cdiv(last_q, block_k))
-    else:
-        nk_eff = nk
+    nk = _kv_tiles(q0, bq, T // block_k, block_k, causal, q_offset)
 
     def body(i, dq):
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = _dot_nt(q, kb) * scale
-        msk = _mask(q0, i * block_k, s.shape, causal, window, q_offset)
-        if msk is not None:
-            s = jnp.where(msk, s, NEG_INF)
+        kb = k_ref[0, pl.ds(i * block_k, block_k), :]
+        vb = v_ref[0, pl.ds(i * block_k, block_k), :]
+        s = _masked(_dot_nt(q, kb) * scale, q0, i * block_k, causal, window,
+                    q_offset)
         p = jnp.exp(s - lse)                          # (bq, bk)
         dp = _dot_nt(do, vb)
         ds = p * (dp - delta) * scale
-        return dq + jnp.dot(ds, kb, preferred_element_type=jnp.float32)
+        return dq + _dot(ds.astype(kb.dtype), kb)
 
-    dq = jax.lax.fori_loop(0, nk_eff, body, jnp.zeros((bq, hd), jnp.float32))
+    dq = jax.lax.fori_loop(0, nk, body, jnp.zeros((bq, hd), jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *, scale, causal, window, block_q, q_offset):
-    bk, hd = k_ref.shape[1], k_ref.shape[2]
+    bk, hd, hv = k_ref.shape[1], k_ref.shape[2], v_ref.shape[2]
     S = q_ref.shape[1]
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[0]
+    v = v_ref[0]
     k0 = pl.program_id(1) * bk
-    nq = S // block_q
-    if causal:
-        # rows before this kv block can be skipped
-        first_q = k0 - q_offset
-        start = jnp.maximum(first_q // block_q, 0)
-    else:
-        start = 0
+    # query tiles before this key tile see none of it
+    start = jnp.maximum((k0 - q_offset) // block_q, 0) if causal else 0
 
     def body(j, carry):
         dk, dv = carry
         rows = pl.ds(j * block_q, block_q)
-        qb = q_ref[0, rows, :].astype(jnp.float32)
-        dob = do_ref[0, rows, :].astype(jnp.float32)
+        qb = q_ref[0, rows, :]
+        dob = do_ref[0, rows, :]
         lseb = lse_ref[0, rows, :][:, :1]
         deltab = delta_ref[0, rows, :][:, :1]
-        s = _dot_nt(qb, k) * scale                    # (bq, bk)
-        msk = _mask(j * block_q, k0, s.shape, causal, window, q_offset)
-        if msk is not None:
-            s = jnp.where(msk, s, NEG_INF)
+        s = _masked(_dot_nt(qb, k) * scale, j * block_q, k0, causal, window,
+                    q_offset)                          # (bq, bk)
         p = jnp.exp(s - lseb)
-        dv_new = dv + _dot_tn(p, dob)
+        dv_new = dv + _dot_tn(p.astype(dob.dtype), dob)
         dp = _dot_nt(dob, v)
         ds = p * (dp - deltab) * scale
-        dk_new = dk + _dot_tn(ds, qb)
+        dk_new = dk + _dot_tn(ds.astype(qb.dtype), qb)
         return dk_new, dv_new
 
-    z = jnp.zeros((bk, hd), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start, nq, body, (z, z))
+    z = (jnp.zeros((bk, hd), jnp.float32), jnp.zeros((bk, hv), jnp.float32))
+    dk, dv = jax.lax.fori_loop(start, S // block_q, body, z)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -229,6 +227,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
     """Gradients of flash_attention_fwd; lse: (BH, S) as it returned."""
     BH, S, hd = q.shape
     BKv, T, _ = k.shape
+    hv = v.shape[2]
     G = BH // BKv
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     block_q = min(block_q, S)
@@ -243,8 +242,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, T, hd), lambda i, j: (i // G, 0, 0)),
-            pl.BlockSpec((1, T, hd), lambda i, j: (i // G, 0, 0)),
-            pl.BlockSpec((1, block_q, hd), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, T, hv), lambda i, j: (i // G, 0, 0)),
+            pl.BlockSpec((1, block_q, hv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, block_q, LANES), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, block_q, LANES), lambda i, j: (i, j, 0)),
         ],
@@ -261,21 +260,21 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
         in_specs=[
             pl.BlockSpec((1, S, hd), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, block_k, hd), lambda i, j: (i // G, j, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda i, j: (i // G, j, 0)),
-            pl.BlockSpec((1, S, hd), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, block_k, hv), lambda i, j: (i // G, j, 0)),
+            pl.BlockSpec((1, S, hv), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, S, LANES), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, S, LANES), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, hd), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_k, hv), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, hd), jnp.float32),
-            jax.ShapeDtypeStruct((BH, T, hd), jnp.float32),
+            jax.ShapeDtypeStruct((BH, T, hv), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     dk = dk_h.reshape(BKv, G, T, hd).sum(axis=1).astype(k.dtype)
-    dv = dv_h.reshape(BKv, G, T, hd).sum(axis=1).astype(v.dtype)
+    dv = dv_h.reshape(BKv, G, T, hv).sum(axis=1).astype(v.dtype)
     return dq, dk, dv

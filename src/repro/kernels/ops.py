@@ -31,30 +31,30 @@ def _default_interpret() -> bool:
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=True, window=None, block_q=128,
                     block_k=128, interpret=None):
-    """q: (B,S,H,hd); k/v: (B,T,Kv,hd).  Returns (B,S,H,hd)."""
+    """q: (B,S,H,hd); k: (B,T,Kv,hd); v: (B,T,Kv,hv).  Returns (B,S,H,hv)."""
     o, _ = _flash_fwd_impl(q, k, v, causal, window, block_q, block_k,
                            interpret)
     return o
 
 
-def _fold(q, k, v):
-    B, S, H, hd = q.shape
-    T, Kv = k.shape[1], k.shape[2]
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * Kv, T, hd)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * Kv, T, hd)
-    return qf, kf, vf
+def _heads_first(x):
+    """(B, S, H, d) → (B·H, S, d)."""
+    B, S, H, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B * H, S, d)
+
+
+def _heads_last(x, B):
+    """(B·H, S, d) → (B, S, H, d)."""
+    BH, S, d = x.shape
+    return x.reshape(B, BH // B, S, d).transpose(0, 2, 1, 3)
 
 
 def _flash_fwd_impl(q, k, v, causal, window, block_q, block_k, interpret):
     interpret = _default_interpret() if interpret is None else interpret
-    B, S, H, hd = q.shape
-    qf, kf, vf = _fold(q, k, v)
-    of, lse = _fa.flash_attention_fwd(qf, kf, vf, causal=causal,
-                                      window=window, block_q=block_q,
-                                      block_k=block_k, interpret=interpret)
-    o = of.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
-    return o, lse
+    of, lse = _fa.flash_attention_fwd(
+        _heads_first(q), _heads_first(k), _heads_first(v), causal=causal,
+        window=window, block_q=block_q, block_k=block_k, interpret=interpret)
+    return _heads_last(of, q.shape[0]), lse
 
 
 def _flash_vjp_fwd(q, k, v, causal, window, block_q, block_k, interpret):
@@ -66,18 +66,12 @@ def _flash_vjp_fwd(q, k, v, causal, window, block_q, block_k, interpret):
 def _flash_vjp_bwd(causal, window, block_q, block_k, interpret, res, do):
     q, k, v, o, lse = res
     interpret_ = _default_interpret() if interpret is None else interpret
-    B, S, H, hd = q.shape
-    T, Kv = k.shape[1], k.shape[2]
-    qf, kf, vf = _fold(q, k, v)
-    of = o.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-    dof = do.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
     dqf, dkf, dvf = _fa.flash_attention_bwd(
-        qf, kf, vf, of, lse, dof, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=interpret_)
-    dq = dqf.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
-    dk = dkf.reshape(B, Kv, T, hd).transpose(0, 2, 1, 3)
-    dv = dvf.reshape(B, Kv, T, hd).transpose(0, 2, 1, 3)
-    return dq, dk, dv
+        *map(_heads_first, (q, k, v, o)), lse, _heads_first(do),
+        causal=causal, window=window, block_q=block_q, block_k=block_k,
+        interpret=interpret_)
+    B = q.shape[0]
+    return _heads_last(dqf, B), _heads_last(dkf, B), _heads_last(dvf, B)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

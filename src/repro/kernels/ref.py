@@ -12,7 +12,8 @@ NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 def flash_attention_ref(q, k, v, causal: bool = True,
                         window: int | None = None, scale: float | None = None):
-    """q: (B,S,H,hd); k/v: (B,T,Kv,hd) with H = Kv·G.  fp32 softmax."""
+    """q: (B,S,H,hd); k: (B,T,Kv,hd); v: (B,T,Kv,hv) with H = Kv·G.  fp32
+    softmax."""
     B, S, H, hd = q.shape
     T, Kv = k.shape[1], k.shape[2]
     G = H // Kv
@@ -30,7 +31,7 @@ def flash_attention_ref(q, k, v, causal: bool = True,
     s = jnp.where(mask[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgst,btkd->bskgd", p, v.astype(jnp.float32))
-    return o.reshape(B, S, H, hd).astype(q.dtype)
+    return o.reshape(B, S, H, v.shape[-1]).astype(q.dtype)
 
 
 def rmsnorm_ref(x, scale, eps: float = 1e-6):

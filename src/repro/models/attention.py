@@ -4,7 +4,7 @@ steps against KV caches.
 
 The chunked path is the jnp reference of the Pallas flash kernel
 (kernels/flash_attention); the Pallas kernel swaps in on TPU via
-``cfg.use_flash``.
+``cfg.use_flash``, for GQA and for MLA (``_flash``).
 """
 
 from __future__ import annotations
@@ -14,11 +14,16 @@ import math
 import jax
 from jax.ad_checkpoint import checkpoint_name
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from ..distributed.sharding import shard
+from ..distributed.sharding import current_mesh, prune_pspec, pspec, shard
 from .layers import PSpec, apply_rope, rmsnorm
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+# the flash kernel's tiles for MLA (qk 96, v 64): of 128-1024, 512 x 512 ran
+# the forward and backward kernels fastest on a TPU v5e at 40 heads x 2048
+MLA_FLASH_BLOCK_Q = 512
+MLA_FLASH_BLOCK_K = 512
 
 
 # ---------------------------------------------------------------------------
@@ -61,6 +66,36 @@ def _causal_mask(S: int, T: int, window: int | None, q_offset: int = 0):
     return m
 
 
+def _flash(q, k, v, window=None, block_q=128, block_k=128):
+    """Causal attention through the Pallas flash kernel (interpret mode off a
+    TPU).  q: (B,S,H,hd); k: (B,T,Kv,hd); v: (B,T,Kv,hv) → (B,S,H,hv).
+
+    XLA cannot partition a Mosaic call, so under a mesh every device runs the
+    kernel on its own block: the batch over the data axes, the heads over the
+    model axis where that divides both H and Kv, else whole."""
+    from ..kernels.ops import flash_attention
+
+    def call(q, k, v):
+        return flash_attention(q, k, v, True, window, block_q, block_k)
+
+    mesh = current_mesh()
+    if mesh is None:
+        return call(q, k, v)
+
+    def spec(x):
+        s = tuple(prune_pspec(x.shape, pspec(("batch", None, "heads", None),
+                                             mesh), mesh))
+        return s + (None,) * (4 - len(s))
+
+    qs, ks = spec(q), spec(k)
+    if qs[2] != ks[2]:
+        qs = ks = (qs[0], None, None, None)
+    qs, ks = P(*qs), P(*ks)
+    # the kernel's outputs carry no varying-axes (vma) annotation
+    return jax.shard_map(call, mesh=mesh, in_specs=(qs, ks, ks),
+                         out_specs=qs, check_vma=False)(q, k, v)
+
+
 def gqa_attention(p, x, cfg, positions, window: int | None = None):
     """Training / prefill self-attention.  x: (B,S,D) → (B,S,D)."""
     B, S, D = x.shape
@@ -73,9 +108,8 @@ def gqa_attention(p, x, cfg, positions, window: int | None = None):
         # Pallas TPU kernel (kernels/flash_attention); interpret-mode on CPU
         if S % 128:
             raise ValueError(f"use_flash needs seq_len % 128 == 0, got {S}")
-        from ..kernels.ops import flash_attention as _flash
         qh = q.reshape(B, S, H, hd)
-        ctx = _flash(qh, k, v, True, window).reshape(B, S, Kv, G, hd)
+        ctx = _flash(qh, k, v, window).reshape(B, S, Kv, G, hd)
     elif cfg.attn_chunked and S > cfg.attn_chunk:
         ctx = _chunked_attention(q, k, v, cfg.attn_chunk, window,
                                  unroll=cfg.scan_unroll)
@@ -193,7 +227,10 @@ def mla_specs(cfg) -> dict:
 
 
 def mla_attention(p, x, cfg, positions):
-    """Training/prefill MLA with explicit K/V materialization."""
+    """Training/prefill MLA.  K and V are expanded per head from the latent
+    (``ckv``); the shared rope key is broadcast over the heads.  With
+    ``cfg.use_flash`` and S a multiple of 128 the fused Pallas kernel runs
+    (scores stay in VMEM); otherwise the S×S scores are materialised."""
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.n_heads
@@ -208,15 +245,22 @@ def mla_attention(p, x, cfg, positions):
     k_nope = (ckv @ p["wun"]).reshape(B, S, H, m.qk_nope_dim)
     v = (ckv @ p["wuv"]).reshape(B, S, H, m.v_head_dim)
 
-    scale = 1.0 / math.sqrt(m.qk_head_dim)
-    scores = (jnp.einsum("bshd,bthd->bhst", q_nope, k_nope) +
-              jnp.einsum("bshd,btxd->bhst", q_rope, k_rope)) * scale
-    mask = _causal_mask(S, S, None)
-    scores = jnp.where(mask[None, None], scores, NEG_INF)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), -1).astype(x.dtype)
-    ctx = jnp.einsum("bhst,bthd->bshd", probs, v).reshape(
-        B, S, H * m.v_head_dim)
-    ctx = checkpoint_name(ctx, "attn_out")
+    if cfg.use_flash and S % 128 == 0:
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (B, S, H, m.qk_rope_dim))],
+            axis=-1)
+        with jax.named_scope("flash"):
+            ctx = _flash(q, k, v, None, MLA_FLASH_BLOCK_Q, MLA_FLASH_BLOCK_K)
+    else:
+        scale = 1.0 / math.sqrt(m.qk_head_dim)
+        scores = (jnp.einsum("bshd,bthd->bhst", q_nope, k_nope) +
+                  jnp.einsum("bshd,btxd->bhst", q_rope, k_rope)) * scale
+        mask = _causal_mask(S, S, None)
+        scores = jnp.where(mask[None, None], scores, NEG_INF)
+        probs = jax.nn.softmax(scores.astype(jnp.float32), -1).astype(x.dtype)
+        ctx = jnp.einsum("bhst,bthd->bshd", probs, v)
+    ctx = checkpoint_name(ctx.reshape(B, S, H * m.v_head_dim), "attn_out")
     return shard(ctx @ p["wo"], "batch", "seq", "embed_act")
 
 

@@ -1,7 +1,8 @@
 """Compile-only guards for the TPU: every Pallas kernel at real widths, the
-whole gemma3-1b train step, and the MiniCPM3 smoke step with and without
-its named scopes, compiled for one chip of a described ``v5e:2x2``
-topology (no chip attached).  Interpret-mode tests cannot see
+whole gemma3-1b train step, the benchmark's 6-layer MiniCPM3 train step,
+and the MiniCPM3 smoke step with and without its named scopes, compiled
+for one chip of a described ``v5e:2x2`` topology, and the MiniCPM3 step
+for all four as a mesh (no chip attached).  Interpret-mode tests cannot see
 what this catches: block tilings the chip refuses, operations Mosaic cannot
 lower, programs that do not fit the chip's memory.
 
@@ -17,6 +18,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from dataclasses import replace
 from jax.sharding import SingleDeviceSharding
@@ -27,6 +29,8 @@ from repro.kernels import ops
 
 GEMMA = get_config("gemma3-1b")
 MAMBA = get_config("mamba2-1.3b")
+# the benchmark cell's depth: 6 of MiniCPM3-4B's 62 layers at full width
+MINICPM = replace(get_config("minicpm3-4b"), n_layers=6)
 BATCH, SEQ = 2, 1024
 
 
@@ -74,6 +78,22 @@ def test_flash_attention_compiles(one_chip, window):
     _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
 
 
+def test_mla_flash_attention_compiles(one_chip):
+    """The kernel at MLA's widths (40 heads, qk 96, v 64; 1 x 2048) with the
+    blocks ``mla_attention`` passes, forward and backward."""
+    from repro.models.attention import MLA_FLASH_BLOCK_K, MLA_FLASH_BLOCK_Q
+    m, H = MINICPM.mla, MINICPM.n_heads
+    qk = _sds(one_chip, (1, 2048, H, m.qk_head_dim), jnp.bfloat16)
+    v = _sds(one_chip, (1, 2048, H, m.v_head_dim), jnp.bfloat16)
+
+    def loss(q, k, v):
+        o = ops.flash_attention(q, k, v, True, None, MLA_FLASH_BLOCK_Q,
+                                MLA_FLASH_BLOCK_K, False)
+        return o.astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
+
+
 def test_fused_adam_compiles(one_chip):
     shp = (GEMMA.d_model, GEMMA.d_ff)
     bf = _sds(one_chip, shp, jnp.bfloat16)
@@ -101,33 +121,79 @@ def test_ssd_chunk_compiles(one_chip):
              _sds(one_chip, (BH,), jnp.float32))
 
 
+def _train_step(one_chip, cfg, shape, mesh=None):
+    """The Trainer's jitted step compiled for the chip, or for ``mesh`` (the
+    step's own shardings place each argument), and the bytes of the
+    parameters and optimizer state it takes."""
+    from repro.data.pipeline import input_specs
+    from repro.distributed.sharding import use_mesh
+    from repro.launch.train import Trainer
+    from repro.models.transformer import abstract_params
+
+    def put(tree):
+        where = one_chip if mesh is None else None
+        return jax.tree.map(lambda s: _sds(where, s.shape, s.dtype), tree)
+
+    tr = Trainer(cfg, shape, mesh)
+    aparams = put(abstract_params(cfg))
+    aopt = put(jax.eval_shape(tr.opt.init, aparams))
+    with use_mesh(mesh):
+        compiled = tr.step_jit.lower(
+            aparams, aopt, put(input_specs(cfg, shape)),
+            put(jax.ShapeDtypeStruct((), jnp.int32))).compile()
+    state = sum(math.prod(x.shape) * x.dtype.itemsize
+                for x in jax.tree.leaves((aparams, aopt)))
+    return compiled, state
+
+
 @pytest.mark.parametrize("use_flash", [False, True])
 def test_gemma3_train_step_compiles(one_chip, monkeypatch, use_flash):
     """The Trainer's own jitted step at full width, batch 2 x 1024: it must
     fit one chip (the compiler refuses it otherwise) and update the
     parameters and moments in place."""
-    from repro.data.pipeline import input_specs
-    from repro.launch.train import Trainer
-    from repro.models.transformer import abstract_params
-
     # the kernels pick interpret mode from the process's backend (CPU here)
     monkeypatch.setattr(ops, "_default_interpret", lambda: False)
     cfg = replace(GEMMA, use_flash=use_flash)
-    shape = ShapeConfig("chip_compile", SEQ, BATCH, "train")
-    tr = Trainer(cfg, shape)
-
-    def put(tree):
-        return jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), tree)
-
-    aparams = put(abstract_params(cfg))
-    aopt = put(jax.eval_shape(tr.opt.init, aparams))
-    compiled = tr.step_jit.lower(aparams, aopt, put(input_specs(cfg, shape)),
-                                 _sds(one_chip, (), jnp.int32)).compile()
+    compiled, state = _train_step(
+        one_chip, cfg, ShapeConfig("chip_compile", SEQ, BATCH, "train"))
     mem = compiled.memory_analysis()
-    state = sum(math.prod(x.shape) * x.dtype.itemsize
-                for x in jax.tree.leaves((aparams, aopt)))
     assert mem.alias_size_in_bytes >= state     # params + moments donated
     assert ("tpu_custom_call" in compiled.as_text()) == use_flash
+
+
+def test_minicpm3_train_step_compiles(one_chip, monkeypatch):
+    """The benchmark cell's step, 6 MiniCPM3 layers at 1 x 2048: every
+    Pallas call is MLA's flash kernel, under ``mla/flash``; no buffer holds
+    the [..., 40, 2048, 2048] scores; the temporaries stay under 4 GiB
+    (8.56 GiB with materialised scores)."""
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    compiled, _ = _train_step(one_chip, MINICPM,
+                              ShapeConfig("chip_compile", 2048, 1, "train"))
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all("/mla/flash/" in line for line in calls)
+    assert not re.search(r"\[(?:\d+,)*40,2048,2048\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+
+def test_minicpm3_mesh_train_step_compiles(topo, monkeypatch):
+    """The same 6 layers at 2 x 2048 on a (data=2, model=2) mesh of the
+    described chips.  XLA cannot partition a Mosaic call, so MLA's kernel
+    runs inside a ``shard_map``: each chip takes one sequence and 20 of the
+    40 heads, and no chip holds the whole batch or every head."""
+    from jax.sharding import Mesh
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    compiled, _ = _train_step(None, MINICPM,
+                              ShapeConfig("chip_compile", 2048, 2, "train"),
+                              mesh)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all("/mla/flash/" in line for line in calls)
+    # the kernel's operands are (batch x heads, S, 96): one sequence, 20 heads
+    assert all("bf16[20,2048,96]" in line for line in calls)
 
 
 # debug information only: op_name and source lines, and the tables of files,
@@ -137,32 +203,30 @@ _DEBUG_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
 
 
 def _without_debug_info(hlo: str) -> str:
-    return "\n\n".join(block for block in _METADATA.sub("", hlo).split("\n\n")
+    """``hlo`` without its debug information, and with each instruction and
+    computation renamed by its first appearance: XLA names a custom call
+    after its innermost scope (``%flash.31``, else ``%closed_call.31``)."""
+    text = "\n\n".join(block for block in _METADATA.sub("", hlo).split("\n\n")
                         if block.split("\n", 1)[0] not in _DEBUG_TABLES)
+    names: dict[str, str] = {}
+    return re.sub(r"%[\w.-]+",
+                  lambda m: names.setdefault(m.group(), f"%v{len(names)}"),
+                  text)
 
 
 def test_named_scopes_add_no_operations(one_chip, monkeypatch):
-    """The MiniCPM3 step (smoke widths), compiled for the chip with the
+    """The MiniCPM3 step (smoke widths at S = 256, so MLA runs the Mosaic
+    flash kernel, as in the cell), compiled for the chip with the
     model's named scopes and with ``jax.named_scope`` a no-op: the optimized
     HLO is the same once debug information is stripped."""
     from repro.configs import smoke_config
-    from repro.data.pipeline import input_specs
-    from repro.launch.train import Trainer
-    from repro.models.transformer import abstract_params
 
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
     cfg = smoke_config("minicpm3-4b")
     shape = ShapeConfig("chip_compile", 256, 2, "train")
 
-    def put(tree):
-        return jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), tree)
-
     def optimized_hlo():
-        tr = Trainer(cfg, shape)
-        aparams = put(abstract_params(cfg))
-        return tr.step_jit.lower(
-            aparams, put(jax.eval_shape(tr.opt.init, aparams)),
-            put(input_specs(cfg, shape)),
-            _sds(one_chip, (), jnp.int32)).compile().as_text()
+        return _train_step(one_chip, cfg, shape)[0].as_text()
 
     scoped = optimized_hlo()
     monkeypatch.setattr(jax, "named_scope",
