@@ -64,10 +64,11 @@ def ssd_chunk_ref(x, dt, b, c, a):
     bf = b.astype(jnp.float32)
     cf = c.astype(jnp.float32)
     cum = jnp.cumsum(dtf, axis=2) * a[:, None, None]          # (BH,nc,Q)
-    decay = jnp.exp(cum[..., :, None] - cum[..., None, :])
     Q = x.shape[2]
     mask = jnp.tril(jnp.ones((Q, Q), bool))
-    decay = jnp.where(mask, decay, 0.0)
+    # masked before exp, which overflows above the diagonal
+    decay = jnp.exp(jnp.where(mask, cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf))
     att = jnp.einsum("hcin,hcjn->hcij", cf, bf) * decay
     dtx = xf * dtf[..., None]
     y = jnp.einsum("hcij,hcjp->hcip", att, dtx)

@@ -43,7 +43,9 @@ def _ssd_chunk_kernel(x_ref, dt_ref, cumc_ref, cumr_ref, b_ref, c_ref,
 
     qi = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     ki = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    decay = jnp.where(qi >= ki, jnp.exp(cum_c - cum_r), 0.0)
+    # masked before exp: above the diagonal cum_c - cum_r is positive and
+    # grows with the chunk, so exp would overflow to inf there
+    decay = jnp.exp(jnp.where(qi >= ki, cum_c - cum_r, -jnp.inf))
 
     att = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32) * decay

@@ -80,6 +80,15 @@ class Trainer:
         cfg = self.cfg
         step_fn = make_train_step(cfg, self.opt, grad_accum=self.grad_accum)
         if self.mesh is not None:
+            mesh, unmeshed = self.mesh, step_fn
+
+            def step_fn(*args):
+                # the model's activation constraints read the active mesh
+                # while tracing: without it ``step_jit.lower`` from outside
+                # ``use_mesh`` builds another, unsharded program
+                with use_mesh(mesh):
+                    return unmeshed(*args)
+
             with use_mesh(self.mesh):
                 p_ax = param_axes(cfg)
                 aparams = abstract(param_specs(cfg))
@@ -156,9 +165,8 @@ class Trainer:
                                     step == inject_failure_at:
                                 inject_failure_at = None
                                 raise RuntimeError("injected node failure")
-                            with use_mesh(self.mesh):
-                                params, opt_state, metrics = self.step_jit(
-                                    params, opt_state, batch, jnp.int32(step))
+                            params, opt_state, metrics = self.step_jit(
+                                params, opt_state, batch, jnp.int32(step))
                         with spans.span("fit.sync") as synced:
                             metrics = jax.tree.map(float,
                                                    jax.device_get(metrics))

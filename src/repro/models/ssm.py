@@ -59,10 +59,11 @@ def ssd_apply(p: dict, x, cfg):
     z = x @ p["wz"]
     xin = x @ p["wx"]
     bc = x @ p["wbc"]
-    xin = jax.nn.silu(_causal_conv(xin.astype(jnp.float32),
-                                   p["conv_x"])).astype(x.dtype)
-    bc = jax.nn.silu(_causal_conv(bc.astype(jnp.float32),
-                                  p["conv_bc"])).astype(x.dtype)
+    with jax.named_scope("conv"):
+        xin = jax.nn.silu(_causal_conv(xin.astype(jnp.float32),
+                                       p["conv_x"])).astype(x.dtype)
+        bc = jax.nn.silu(_causal_conv(bc.astype(jnp.float32),
+                                      p["conv_bc"])).astype(x.dtype)
     xin = shard(xin, "batch", "seq", "ffn")
     Bm, Cm = jnp.split(bc.reshape(B_, S, 2 * G, N), 2, axis=2)   # (B,S,G,N)
     dt = jax.nn.softplus(
@@ -84,21 +85,25 @@ def ssd_apply(p: dict, x, cfg):
     total = cum[:, :, -1]                                         # (B,nc,nh)
     dtx = xc * dtc[..., None].astype(xc.dtype)                    # (B,nc,Q,nh,hp)
 
-    # intra-chunk (quadratic, masked decay kernel)
-    li = cum[:, :, :, None, :]                                    # i
-    lj = cum[:, :, None, :, :]                                    # j
-    decay = jnp.exp(li - lj)                                      # (B,nc,Q,Q,nh)
-    mask = jnp.tril(jnp.ones((Q, Q), bool))
-    decay = jnp.where(mask[None, None, ..., None], decay, 0.0)
-    cb = jnp.einsum("bcihn,bcjhn->bcijh", Cc, Bc).astype(jnp.float32)
-    att = cb * decay
-    y_intra = jnp.einsum("bcijh,bcjhp->bcihp", att.astype(xc.dtype), dtx)
+    # intra-chunk (quadratic, masked decay kernel).  The mask goes inside the
+    # exponential: above the diagonal li - lj sums -dt*A over up to Q-1
+    # positions and exp overflows to inf, and the backward pass of a where
+    # applied after exp multiplies its zero cotangent by that inf (NaN).
+    with jax.named_scope("intra"):
+        li = cum[:, :, :, None, :]                                # i
+        lj = cum[:, :, None, :, :]                                # j
+        mask = jnp.tril(jnp.ones((Q, Q), bool))[None, None, ..., None]
+        decay = jnp.exp(jnp.where(mask, li - lj, -jnp.inf))       # (B,nc,Q,Q,nh)
+        cb = jnp.einsum("bcihn,bcjhn->bcijh", Cc, Bc).astype(jnp.float32)
+        att = cb * decay
+        y_intra = jnp.einsum("bcijh,bcjhp->bcihp", att.astype(xc.dtype), dtx)
 
     # chunk summary states: (B,nc,nh,hp,N)
-    sdecay = jnp.exp(total[:, :, None] - cum)                     # (B,nc,Q,nh)
-    states = jnp.einsum("bcjhn,bcjhp->bchpn",
-                        (Bc.astype(jnp.float32) *
-                         sdecay[..., None]).astype(xc.dtype), dtx)
+    with jax.named_scope("states"):
+        sdecay = jnp.exp(total[:, :, None] - cum)                 # (B,nc,Q,nh)
+        states = jnp.einsum("bcjhn,bcjhp->bchpn",
+                            (Bc.astype(jnp.float32) *
+                             sdecay[..., None]).astype(xc.dtype), dtx)
 
     # inter-chunk recurrence
     def step(carry, inp):
@@ -107,17 +112,19 @@ def ssd_apply(p: dict, x, cfg):
         new = st_prev * jnp.exp(tot_c)[:, :, None, None] + st_c
         return new, st_prev
 
-    init = jnp.zeros((B_, nh, hp, N), jnp.float32)
-    _, prev_states = jax.lax.scan(
-        step, init, (states.astype(jnp.float32).transpose(1, 0, 2, 3, 4),
-                     total.transpose(1, 0, 2)),
-        unroll=min(cfg.scan_unroll, nc))
-    prev_states = prev_states.transpose(1, 0, 2, 3, 4)            # (B,nc,nh,hp,N)
+    with jax.named_scope("scan"):
+        init = jnp.zeros((B_, nh, hp, N), jnp.float32)
+        _, prev_states = jax.lax.scan(
+            step, init, (states.astype(jnp.float32).transpose(1, 0, 2, 3, 4),
+                         total.transpose(1, 0, 2)),
+            unroll=min(cfg.scan_unroll, nc))
+        prev_states = prev_states.transpose(1, 0, 2, 3, 4)        # (B,nc,nh,hp,N)
 
-    y_inter = jnp.einsum("bcihn,bchpn->bcihp",
-                         (Cc.astype(jnp.float32) *
-                          jnp.exp(cum)[..., None]).astype(xc.dtype),
-                         prev_states.astype(xc.dtype))
+    with jax.named_scope("inter"):
+        y_inter = jnp.einsum("bcihn,bchpn->bcihp",
+                             (Cc.astype(jnp.float32) *
+                              jnp.exp(cum)[..., None]).astype(xc.dtype),
+                             prev_states.astype(xc.dtype))
     y = (y_intra + y_inter).reshape(B_, S, nh, hp)
     y = y + xh * p["D"][..., None].astype(xh.dtype)
     y = y.reshape(B_, S, di)

@@ -2,9 +2,10 @@
 whole gemma3-1b train step, the benchmark's 6-layer MiniCPM3 train step,
 and the MiniCPM3 smoke step with and without its named scopes, compiled
 for one chip of a described ``v5e:2x2`` topology, and the MiniCPM3 step
-for all four as a mesh (no chip attached).  Interpret-mode tests cannot see
-what this catches: block tilings the chip refuses, operations Mosaic cannot
-lower, programs that do not fit the chip's memory.
+and the benchmark's whole Mamba-2 step for all four as a mesh (no chip
+attached).  Interpret-mode tests cannot see what this catches: block
+tilings the chip refuses, operations Mosaic cannot lower, programs that do
+not fit the chip's memory.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler library at a time, and every worker of a
@@ -195,6 +196,30 @@ def test_minicpm3_mesh_train_step_compiles(topo, monkeypatch):
     # the kernel's operands are (batch x heads, S, 96): one sequence, 20 heads
     assert all("bf16[20,2048,96]" in line for line in calls)
 
+
+
+def test_mamba2_mesh_train_step_compiles(topo):
+    """The benchmark's Mamba-2 cell: all 48 layers at 2 x 4096 on a
+    (data=2, model=2) mesh of the described chips, lowered from plain
+    shapes outside ``use_mesh``, as the benchmark lowers the step to read
+    its memory.  The step enters its own mesh, so this is the sharded
+    program (3.43 GiB of arguments and 9.27 GiB of temporaries a chip);
+    without the mesh's activation constraints it needs 19.04 GiB and the
+    compiler refuses it."""
+    from jax.sharding import Mesh
+    from repro.data.pipeline import input_specs
+    from repro.launch.train import Trainer
+    from repro.models.transformer import abstract_params
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    shape = ShapeConfig("chip_compile", 4096, 2, "train")
+    tr = Trainer(MAMBA, shape, mesh)
+    aparams = abstract_params(MAMBA)
+    compiled = tr.step_jit.lower(
+        aparams, jax.eval_shape(tr.opt.init, aparams),
+        input_specs(MAMBA, shape),
+        jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13 * 2**30
 
 # debug information only: op_name and source lines, and the tables of files,
 # functions and stack frames they point into

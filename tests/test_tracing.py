@@ -5,6 +5,7 @@ benchmark's readers of both (``chipbench/metrics/``)."""
 import collections
 import importlib.util
 import re
+import sys
 from pathlib import Path
 
 import jax
@@ -19,6 +20,8 @@ from repro.launch.train import Trainer
 SHAPE = ShapeConfig("t", seq_len=64, global_batch=4, kind="train")
 LOOP = ["fit.data", "fit.step", "fit.sync", "fit.log"]
 METRICS = Path(__file__).resolve().parents[1] / "chipbench" / "metrics"
+if str(METRICS.parents[1]) not in sys.path:     # readers import chipbench
+    sys.path.insert(0, str(METRICS.parents[1]))
 
 
 @pytest.fixture(scope="module")
@@ -121,7 +124,9 @@ def test_spans_match_their_profiler_twins(trainer, tmp_path):
 
 @pytest.mark.parametrize("arch, scopes", [
     ("minicpm3-4b", ["embed", "mixer", "mla", "mlp", "loss", "optimizer"]),
-    ("mamba2-1.3b", ["embed", "mixer", "ssd", "loss", "optimizer"]),
+    ("mamba2-1.3b", ["embed", "mixer", "ssd", "ssd/conv", "ssd/intra",
+                     "ssd/states", "ssd/scan", "ssd/inter", "loss",
+                     "optimizer"]),
 ])
 def test_step_hlo_carries_the_scopes(arch, scopes):
     from repro.data.pipeline import input_specs
@@ -182,3 +187,44 @@ def test_reader_on_a_hand_built_record(monkeypatch, name, value):
     assert read({"trace": {"steps": 2}}) == pytest.approx(value)
     with pytest.raises(ValueError, match="expected 3"):
         read({"trace": {"steps": 3}})
+
+
+# -- the mesh cell's readers on the recorded four-device trace -----------------
+
+BENCH = METRICS.parent
+
+
+def _recorded(chips: int) -> dict:
+    import json
+    from chipbench import trace
+    ev = json.loads((BENCH / "tests" / "data" / f"tiny_trace_{chips}.json")
+                    .read_text())
+    return trace.reduce(ev, chips)
+
+
+@pytest.mark.parametrize("chips, value", [
+    (4, 0.05880675),    # 235.227 us exposed on device 0 over 4 steps
+    (1, 0.0),           # one chip: no collective
+])
+def test_collective_exposed_ms_on_a_recorded_trace(chips, value):
+    got = _reader("collective_exposed_ms")({"trace": _recorded(chips)})
+    assert got == pytest.approx(value)
+
+
+def test_train_mfu_mesh4_on_the_recorded_four_device_trace():
+    import json
+    from types import SimpleNamespace
+    from chipbench import cells
+    tr = _recorded(4)
+    conf = json.loads((BENCH / "configs" / "mamba2-1.3b.json").read_text())
+    traffic = json.loads((BENCH / "traffic" / "train.s4096.b2.mesh2x2.json")
+                         .read_text())
+    model = cells.load_module(BENCH / "configs" / "mamba2-1.3b.py")
+    cell = SimpleNamespace(config=conf, traffic=traffic, model=model)
+    ctx = {"cell": cell, "trace": tr, "tokens_per_step": 2 * 4096,
+           "devices": [None] * 4, "peaks": {"bf16_flops": 197e12}}
+    flops = model.flops_per_token(conf, 4096)
+    want = 100 * flops * 8192 * tr["steps"] / tr["window_s"] / (4 * 197e12)
+    got = _reader("train_mfu_mesh4")(ctx)
+    assert got == pytest.approx(want)
+    assert got == _reader("train_mfu")(ctx)

@@ -104,6 +104,31 @@ def test_trainer_on_mesh_matches_no_mesh(cfg):
         assert leaf.sharding == sh
 
 
+
+def test_mesh_step_lowers_alike_in_and_out_of_use_mesh(cfg):
+    """The model's activation constraints read the active mesh while the
+    step is traced.  The jitted step enters its own mesh, so lowering it
+    from outside ``use_mesh`` (as the chip benchmark does to read the
+    step's memory) gives the program ``fit`` runs, constraints and all."""
+    from repro.data.pipeline import input_specs
+    from repro.distributed.sharding import use_mesh
+    from repro.launch.mesh import make_mesh
+    from repro.models.transformer import abstract_params
+    mesh = make_mesh((1, 1), ("data", "model"))
+    aparams = abstract_params(cfg)
+
+    def lowered(tr):
+        return tr.step_jit.lower(
+            aparams, jax.eval_shape(tr.opt.init, aparams),
+            input_specs(cfg, SHAPE),
+            jax.ShapeDtypeStruct((), jnp.int32)).as_text()
+
+    outside = lowered(Trainer(cfg, SHAPE, mesh))
+    with use_mesh(mesh):
+        inside = lowered(Trainer(cfg, SHAPE, mesh))
+    assert "sharding_constraint" in inside
+    assert outside == inside
+
 def test_compile_cache_location(monkeypatch):
     from repro.launch import compile_cache as cc
     before = jax.config.jax_compilation_cache_dir
