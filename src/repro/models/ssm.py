@@ -8,11 +8,15 @@ buffer and the SSM state as cache.  All cumulative/decay terms in fp32.
 
 from __future__ import annotations
 
+import math
+
 import jax
 from jax.ad_checkpoint import checkpoint_name
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from ..distributed.sharding import shard
+from ..distributed.sharding import (current_mesh, prune_pspec, pspec,
+                                    resolve_axes, shard)
 from .layers import PSpec, rmsnorm
 
 
@@ -46,6 +50,40 @@ def _causal_conv(u, w):
     return out
 
 
+def _in_proj(p: dict, x):
+    """The column-parallel in-projections x @ wz, x @ wx, x @ wdt.
+
+    Each weight's columns are split over the mesh axes of ``ffn``, so each
+    product's input gradient is a partial sum over them.  Where those axes
+    divide every projection's width, the products run in a ``shard_map``
+    that takes x whole over them: the backward adds the three local input
+    gradients on each chip and reduces that one sum with a single psum,
+    where the partitioner would all-reduce each partial on its own (3x the
+    bytes).  The weights enter gathered over their FSDP (``embed``) axes,
+    so those gathers and the reduce-scatters of the weights' gradients stay
+    the partitioner's.  Otherwise (no mesh, one device) the plain products."""
+    ws = p["wz"], p["wx"], p["wdt"]
+    mesh = current_mesh()
+    axes = resolve_axes("ffn", mesh) if mesh is not None else ()
+    n = math.prod(int(mesh.shape[a]) for a in axes)
+    if n == 1 or any(w.shape[1] % n for w in ws):
+        return tuple(x @ w for w in ws)
+
+    x_spec = prune_pspec(x.shape, pspec(("batch", None, None), mesh), mesh)
+    w_spec = pspec((None, "ffn"), mesh)
+    out_spec = P(x_spec[0] if len(x_spec) else None, None, w_spec[1])
+
+    def body(x, *ws):
+        # x made varying once, so the transpose psums the sum of the three;
+        # each weight promoted before it meets the batch's axes, so its
+        # gradient is summed over them in the product's dtype
+        x = jax.lax.pcast(x, axes, to="varying")
+        return tuple(x @ w.astype(jnp.result_type(x, w)) for w in ws)
+
+    return jax.shard_map(body, mesh=mesh, in_specs=(x_spec, *(w_spec,) * 3),
+                         out_specs=(out_spec,) * 3)(x, *ws)
+
+
 def ssd_apply(p: dict, x, cfg):
     """Full-sequence SSD.  x: (B,S,D) → (B,S,D)."""
     s = cfg.ssm
@@ -56,8 +94,8 @@ def ssd_apply(p: dict, x, cfg):
     assert S % Q == 0, (S, Q)
     nc = S // Q
 
-    z = x @ p["wz"]
-    xin = x @ p["wx"]
+    with jax.named_scope("in_proj"):
+        z, xin, dt_in = _in_proj(p, x)
     bc = x @ p["wbc"]
     with jax.named_scope("conv"):
         xin = jax.nn.silu(_causal_conv(xin.astype(jnp.float32),
@@ -66,8 +104,7 @@ def ssd_apply(p: dict, x, cfg):
                                       p["conv_bc"])).astype(x.dtype)
     xin = shard(xin, "batch", "seq", "ffn")
     Bm, Cm = jnp.split(bc.reshape(B_, S, 2 * G, N), 2, axis=2)   # (B,S,G,N)
-    dt = jax.nn.softplus(
-        (x @ p["wdt"]).astype(jnp.float32) + p["dt_bias"])        # (B,S,nh)
+    dt = jax.nn.softplus(dt_in.astype(jnp.float32) + p["dt_bias"])  # (B,S,nh)
     A = -jnp.exp(p["A_log"])                                      # (nh,)
 
     xh = xin.reshape(B_, S, nh, hp)

@@ -203,9 +203,14 @@ def test_mamba2_mesh_train_step_compiles(topo):
     (data=2, model=2) mesh of the described chips, lowered from plain
     shapes outside ``use_mesh``, as the benchmark lowers the step to read
     its memory.  The step enters its own mesh, so this is the sharded
-    program (3.43 GiB of arguments and 9.27 GiB of temporaries a chip);
+    program (3.43 GiB of arguments and 9.30 GiB of temporaries a chip);
     without the mesh's activation constraints it needs 19.04 GiB and the
-    compiler refuses it."""
+    compiler refuses it.
+
+    Each layer's backward all-reduces over ``model`` one sum of the three
+    in-projections' input gradients (one ``bf16[1,4096,2048]`` operand,
+    three when the partitioner reduces each partial), and its forward the
+    one output of ``y @ wo``."""
     from jax.sharding import Mesh
     from repro.data.pipeline import input_specs
     from repro.launch.train import Trainer
@@ -220,6 +225,15 @@ def test_mamba2_mesh_train_step_compiles(topo):
         jax.ShapeDtypeStruct((), jnp.int32)).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13 * 2**30
+    width = {"forward": 0, "backward": 0}
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"= (.*?) all-reduce(?:-start)?\(.*"
+                      r'op_name="jit\(step_fn\)/([^"]*)"', line)
+        if m and "/mixer/ssd/" in m.group(2):
+            side = ("backward" if m.group(2).startswith("transpose(jvp())")
+                    else "forward")
+            width[side] += m.group(1).count("bf16[1,4096,2048]")
+    assert width == {"forward": 1, "backward": 1}, width
 
 # debug information only: op_name and source lines, and the tables of files,
 # functions and stack frames they point into

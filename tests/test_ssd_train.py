@@ -8,12 +8,15 @@ widths (d_model 64, 16 heads of 8, d_state 16, 2 layers):
 * every gradient leaf of ``ssd_apply`` finite where the decay's exponent
   overflows above the chunk's diagonal;
 * one train step on a (data=2, model=2) mesh of four CPU devices against
-  the same step on one device.
+  the same step on one device, and on meshes whose ``model`` axis cannot
+  shard the heads (the in-projections' plain path);
+* the in-projections lowered without a mesh: the three plain products.
 
 At chunk 16 over 64 tokens the decay above the diagonal stays finite, so
 only the chunk-256 cases see a decay masked after its exponential."""
 
 import os
+import re
 import subprocess
 import sys
 from dataclasses import replace
@@ -145,3 +148,103 @@ def test_train_step_on_a_mesh_matches_one_device():
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "MESH_SSD_OK" in proc.stdout
+
+
+PLAIN_IN_PROJ = """
+import os
+import re
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+from dataclasses import replace
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs import smoke_config
+from repro.configs.base import ShapeConfig
+from repro.distributed.sharding import use_mesh
+from repro.launch.mesh import make_mesh
+from repro.launch.train import Trainer
+from repro.models.layers import materialize
+from repro.models.ssm import _in_proj, ssm_specs
+
+base = smoke_config("mamba2-1.3b")
+cfg = replace(base, ssm=replace(base.ssm, chunk=256),
+              param_dtype="float32", compute_dtype="float32")
+p = materialize(ssm_specs(cfg), jax.random.PRNGKey(0))
+x = jnp.ones((4, 512, cfg.d_model), jnp.bfloat16)
+
+
+def in_proj_jaxpr(mesh):
+    loss = lambda p, x: sum(o.astype(jnp.float32).sum()
+                            for o in _in_proj(p, x))
+    with use_mesh(mesh):
+        return str(jax.make_jaxpr(jax.grad(loss, (0, 1)))(p, x))
+
+
+# model=2 divides the 16 heads: the map, whose backward psums x's gradient
+# over model once (the weights' gradients over data, one each); no model
+# axis, or model=3, which does not divide: the plain products
+meshed = in_proj_jaxpr(make_mesh((2, 2), ("data", "model")))
+assert "shard_map" in meshed, meshed
+n_psum = len(re.findall(r"psum\\w*\\[\\s*axes=\\('model',\\)", meshed))
+assert n_psum == 1, meshed
+meshes = {"data4": make_mesh((4,), ("data",)),
+          "model3": make_mesh((1, 3), ("data", "model"))}
+for mesh in meshes.values():
+    assert "shard_map" not in in_proj_jaxpr(mesh)
+
+shape = ShapeConfig("mesh", seq_len=512, global_batch=4, kind="train")
+got = {}
+for name, mesh in [("one", None), *meshes.items()]:
+    tr = Trainer(cfg, shape, mesh, seed=2147483659)
+    loss = tr.fit(1)[0]["loss"]
+    params, opt = tr._last_state
+    got[name] = (loss, jax.tree.map(np.asarray, opt["m"]),
+                 [p.dtype for p in jax.tree.leaves(params)])
+l1, g1, dtypes = got["one"]
+for name in meshes:
+    l4, g4, _ = got[name]
+    assert np.isfinite(l1) and abs(l4 - l1) <= 1e-5 * abs(l1), (name, l1, l4)
+    for (k, a), b, dt in zip(jax.tree_util.tree_flatten_with_path(g4)[0],
+                             jax.tree.leaves(g1), dtypes, strict=True):
+        tol = 1e-4 if dt == np.float32 else 3e-3
+        np.testing.assert_allclose(
+            a, b, atol=tol * float(np.max(np.abs(b))),
+            err_msg=name + jax.tree_util.keystr(k))
+print("PLAIN_IN_PROJ_OK")
+"""
+
+
+def test_in_proj_takes_the_plain_path_where_model_cannot_shard_heads():
+    """Four CPU devices (a fresh process): under a (data=2, model=2) mesh
+    the in-projections run in one ``shard_map`` with one psum in their
+    backward; under a mesh with no ``model`` axis, or one of 3 that does not
+    divide the 16 heads, they are the plain products, and a train step
+    matches one device as closely as on the (data=2, model=2) mesh."""
+    env = dict(os.environ, PYTHONPATH=str(ROOT / "src"), JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", PLAIN_IN_PROJ], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "PLAIN_IN_PROJ_OK" in proc.stdout
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (1, 1)])
+def test_in_proj_lowers_to_three_products_on_one_device(mesh_shape):
+    """Without a mesh, or on a mesh of one device, ``_in_proj`` lowers to
+    the operations of ``x @ wz, x @ wx, x @ wdt``: three dot_generals."""
+    from repro.configs import smoke_config
+    from repro.distributed.sharding import use_mesh
+    from repro.launch.mesh import make_mesh
+    from repro.models.layers import materialize
+    from repro.models.ssm import _in_proj, ssm_specs
+    cfg = smoke_config("mamba2-1.3b")
+    p = materialize(ssm_specs(cfg), jax.random.PRNGKey(0))
+    x = jnp.ones((2, 64, cfg.d_model), jnp.bfloat16)
+    mesh = mesh_shape and make_mesh(mesh_shape, ("data", "model"))
+
+    def ops(f):
+        with use_mesh(mesh):
+            text = jax.jit(f).lower(p, x).as_text()
+        return re.findall(r"stablehlo\.\w+", text)
+
+    got = ops(_in_proj)
+    assert got == ops(lambda p, x: (x @ p["wz"], x @ p["wx"], x @ p["wdt"]))
+    assert got.count("stablehlo.dot_general") == 3, got
