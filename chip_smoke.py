@@ -9,9 +9,10 @@ One chip, in order:
   2. the persistent compile cache is turned on (``launch/compile_cache.py``);
   3. gemma3-1b, exactly as the registry holds it, trains for 6 steps at
      batch 2 x 1024 through ``Trainer.fit``; every loss must be finite;
-  4. each Pallas kernel runs compiled at a real width and is compared with
-     ``kernels/ref.py``; then 2 steps with ``use_flash=True`` must compile
-     to a program holding the kernel and start from the same loss.
+  4. the Pallas flash kernel runs compiled at the model's attention shape,
+     forward and backward, and is compared with ``kernels/ref.py``; then 2
+     steps with ``use_flash=True`` must compile to a program holding the
+     kernel and start from the same loss.
 
 Four chips: 3 steps on device 0 alone, then the same 3 steps on a
 ``(data=2, model=2)`` mesh of all four; the losses must agree, every
@@ -41,7 +42,7 @@ ARCH = "gemma3-1b"
 SEQ, BATCH = 1024, 2
 TRAIN_STEPS, FLASH_STEPS, MESH_STEPS = 6, 2, 3
 LOSS_RTOL = 1e-2        # two losses of one bf16 model "agree" within this
-TOL = {"bfloat16": 2e-2, "float32": 1e-5}    # as tests/test_kernels.py
+TOL = 2e-2      # bfloat16, as tests/test_kernels.py
 
 
 class SmokeFailure(Exception):
@@ -113,22 +114,18 @@ def _compare(name: str, got, want, tol: float) -> None:
     check(e <= tol, f"{name}: error {e} > {tol}")
 
 
-def kernel_phase(cfg, ssm_cfg, batch: int, seq: int) -> None:
-    """Each Pallas kernel at the given model widths vs kernels/ref.py."""
+def kernel_phase(cfg, batch: int, seq: int) -> None:
+    """The flash kernel at the given model widths vs kernels/ref.py."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops, ref
 
     keys = iter(jax.random.split(jax.random.PRNGKey(0), 16))
 
-    def rnd(shape, dtype, scale=1.0):
-        return (jax.random.normal(next(keys), shape, jnp.float32)
-                * scale).astype(dtype)
+    def rnd(shape, dtype):
+        return jax.random.normal(next(keys), shape, jnp.float32).astype(dtype)
 
-    bf, f32 = jnp.bfloat16, jnp.float32
-
-    def exact():
-        return jax.default_matmul_precision("highest")
+    bf = jnp.bfloat16
 
     # flash attention, forward and backward, at the model's attention shape
     H, Kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
@@ -148,48 +145,12 @@ def kernel_phase(cfg, ssm_cfg, batch: int, seq: int) -> None:
             return ref.flash_attention_ref(q, k, v, causal=True, window=w)
 
         got = jax.jit(fwd_bwd, static_argnums=0)(kern, do, *qkv)
-        with exact():
+        with jax.default_matmul_precision("highest"):
             want = jax.jit(fwd_bwd, static_argnums=0)(oracle, do, *qkv)
         for part, g, w in zip(("o", "dq", "dk", "dv"), got, want,
                               strict=True):
             _compare(f"flash_attention {qkv[0].shape} window={window} "
-                     f"{part}", g, w, TOL["bfloat16"])
-
-    # fused AdamW on one real leaf: bf16 weight, fp32 moments
-    shp = (cfg.d_model, cfg.d_ff)
-    state = (rnd(shp, bf), rnd(shp, bf), rnd(shp, f32, 0.1),
-             jnp.abs(rnd(shp, f32, 0.01)))
-    hyper = dict(lr=1e-3, weight_decay=0.01)
-    for count in (1, 100):
-        got = jax.jit(lambda p, g, m, v, c=count: ops.fused_adam(
-            p, g, m, v, jnp.int32(c), **hyper))(*state)
-        want = jax.jit(lambda p, g, m, v, c=count: ref.fused_adam_ref(
-            p, g, m, v, count=c, **hyper))(*state)
-        for part, a, b in zip(("p", "m", "v"), got, want, strict=True):
-            _compare(f"fused_adam {shp} count={count} {part}", a, b,
-                     TOL[str(b.dtype)])
-
-    # rmsnorm over (batch*seq, d_model)
-    x = rnd((batch * seq, cfg.d_model), bf)
-    sc = rnd((cfg.d_model,), f32, 0.1)
-    _compare(f"rmsnorm {x.shape}", jax.jit(ops.rmsnorm)(x, sc),
-             jax.jit(ref.rmsnorm_ref)(x, sc), TOL["bfloat16"])
-
-    # SSD intra-chunk at the SSM config's head/state/chunk widths
-    s = ssm_cfg.ssm
-    BH, Q = batch * ssm_cfg.ssm_heads, s.chunk
-    args = (rnd((BH, seq // Q, Q, s.headdim), bf),
-            jnp.abs(rnd((BH, seq // Q, Q), f32, 0.1)),
-            rnd((BH, seq // Q, Q, s.d_state), bf),
-            rnd((BH, seq // Q, Q, s.d_state), bf),
-            -jnp.abs(rnd((BH,), f32)) - 0.1)
-    got = jax.jit(ops.ssd_chunk)(*args)
-    with exact():
-        want = jax.jit(ref.ssd_chunk_ref)(*args)
-    for part, g_, w_, tol in zip(("y", "states", "cum"), got, want,
-                                 (TOL["bfloat16"], TOL["bfloat16"],
-                                  TOL["float32"]), strict=True):
-        _compare(f"ssd_chunk {args[0].shape} {part}", g_, w_, tol)
+                     f"{part}", g, w, TOL)
 
 
 def flash_step_phase(cfg, shape, steps: int, ref_loss: float) -> None:
@@ -285,7 +246,7 @@ def main() -> int:
             mesh_phase(cfg, shape, MESH_STEPS)
         else:
             losses = train_phase(cfg, shape, TRAIN_STEPS)
-            kernel_phase(cfg, get_config("mamba2-1.3b"), BATCH, SEQ)
+            kernel_phase(cfg, BATCH, SEQ)
             flash_step_phase(cfg, shape, FLASH_STEPS, losses[0])
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)

@@ -77,10 +77,10 @@ class ModelConfig:
     compute_dtype: str = "bfloat16"
     state_dtype: str = "float32"     # optimizer states (bf16 for ≥100B archs)
     remat: str = "dots"              # remat policy name (see core.remat_policy)
-    # Pallas flash attention (interpret mode off a TPU; under a mesh inside
-    # a shard_map).  It needs seq_len % 128 == 0: GQA raises otherwise, MLA
-    # keeps its materialised scores (its registry entry sets the flag, and
-    # its smoke runs use short sequences).
+    # Pallas flash attention, the one kernel of `kernels/` (interpret mode
+    # off a TPU; under a mesh inside a shard_map).  It needs seq_len % 128
+    # == 0: GQA raises otherwise, MLA keeps its materialised scores (its
+    # registry entry sets the flag, and its smoke runs use short sequences).
     use_flash: bool = False
     attn_chunked: bool = False       # jnp flash-style chunked attention
     attn_chunk: int = 1024
@@ -88,11 +88,6 @@ class ModelConfig:
     scan_unroll: int = 1             # >1: unroll scans (roofline flop counting)
     seq_sharded_acts: bool = False   # SP: shard residual stream over 'model'
                                      # between blocks (saved scan carry /16)
-    sharded_embed: bool = False      # masked-gather embedding via
-                                     # jax.shard_map: measured ~neutral on
-                                     # peak mem (§Perf iteration 5,
-                                     # hypothesis refuted) — keep XLA's
-                                     # gather by default
     notes: str = ""
 
     # -- derived -------------------------------------------------------------

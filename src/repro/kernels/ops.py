@@ -1,9 +1,7 @@
-"""jit'd public wrappers for the Pallas kernels.
+"""The public wrapper of the Pallas flash-attention kernel.
 
-* auto-`interpret` on CPU (the kernels TARGET TPU; interpret mode executes
+* auto-`interpret` on CPU (the kernel TARGETS TPU; interpret mode executes
   the kernel body in Python for correctness validation);
-* block sizes are halved until they divide the tensor, and refused when
-  that leaves a block the TPU's (8, 128) tiling rule rejects;
 * `flash_attention` carries a custom_vjp wiring the recompute backward;
 * model-facing layouts (B,S,H,hd) are adapted to kernel layouts here.
 """
@@ -15,8 +13,6 @@ import functools
 import jax
 
 from . import flash_attention as _fa
-from . import fused_adam as _ad
-from . import rmsnorm as _rn
 
 
 def _default_interpret() -> bool:
@@ -75,58 +71,3 @@ def _flash_vjp_bwd(causal, window, block_q, block_k, interpret, res, do):
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
-
-
-# ---------------------------------------------------------------------------
-# rmsnorm / fused adam
-# ---------------------------------------------------------------------------
-
-
-def _divisor_block(n: int, block: int, align: int) -> int:
-    """Largest ``block / 2**k`` dividing ``n`` (``n`` itself when smaller).
-    The result must be a multiple of ``align`` or the whole of ``n``."""
-    b = min(block, n)
-    while n % b:
-        b //= 2
-    if b % align and b != n:
-        raise ValueError(f"no {align}-aligned block of at most {block} "
-                         f"divides {n} (largest divisor found: {b})")
-    return b
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "block_rows",
-                                             "interpret"))
-def rmsnorm(x, scale, eps: float = 1e-6, block_rows: int = 256,
-            interpret=None):
-    """x: (..., d)."""
-    interpret = _default_interpret() if interpret is None else interpret
-    shp = x.shape
-    rows = 1
-    for s in shp[:-1]:
-        rows *= s
-    x2 = x.reshape(rows, shp[-1])
-    br = _divisor_block(rows, block_rows, 8)
-    out = _rn.rmsnorm(x2, scale, eps=eps, block_rows=br,
-                      interpret=interpret)
-    return out.reshape(shp)
-
-
-def fused_adam(p, g, m, v, count, lr, b1=0.9, b2=0.95, eps=1e-8,
-               weight_decay=0.0, interpret=None):
-    """Pytree-leaf AdamW step via the fused kernel; any shape (flattened)."""
-    interpret = _default_interpret() if interpret is None else interpret
-    shp = p.shape
-    n = p.size
-    block = _divisor_block(n, 65536, 128)
-    out = _ad.fused_adam(p.reshape(n), g.reshape(n), m.reshape(n),
-                         v.reshape(n), count, lr=lr, b1=b1, b2=b2, eps=eps,
-                         weight_decay=weight_decay, block=block,
-                         interpret=interpret)
-    return tuple(t.reshape(shp) for t in out)
-
-
-def ssd_chunk(x, dt, b, c, a, interpret=None):
-    """Fused SSD intra-chunk (Mamba-2) — see kernels/ssd_chunk.py."""
-    from . import ssd_chunk as _sc
-    interpret = _default_interpret() if interpret is None else interpret
-    return _sc.ssd_chunk(x, dt, b, c, a, interpret=interpret)

@@ -130,8 +130,7 @@ def build_step(arch: str, shape_name: str, mesh, variant: dict | None = None):
     cfg_over = {k: v for k, v in variant.items()
                 if k in ("remat", "attn_chunked", "loss_chunk", "state_dtype",
                          "attn_chunk", "n_layers", "scan_unroll",
-                         "use_flash", "seq_sharded_acts",
-                         "sharded_embed")}
+                         "use_flash", "seq_sharded_acts")}
     if cfg_over:
         cfg = dc_replace(cfg, **cfg_over)
 

@@ -98,46 +98,6 @@ def apply_rope(x, positions, theta: float = 10000.0):
     return out.astype(x.dtype)
 
 
-def embed_lookup(table, tokens, enabled: bool = True):
-    """Vocab-sharded embedding lookup.
-
-    A plain ``table[tokens]`` with the table sharded ('vocab'→model,
-    'embed'→data) hits XLA SPMD's involuntary-full-rematerialization path
-    (the gather result is replicated per device before re-partitioning —
-    a multi-GB transient at nemotron scale).  Here each model-shard gathers
-    from its local vocab slice with out-of-range rows masked to zero and the
-    partials are psum'ed — no replicated intermediate ever exists.
-    """
-    from ..distributed.sharding import current_mesh, prune_pspec
-    from jax.sharding import PartitionSpec as P
-
-    mesh = current_mesh()
-    V, D = table.shape
-    if (not enabled or mesh is None or "model" not in mesh.axis_names
-            or V % int(mesh.shape["model"]) != 0):
-        return table[tokens]
-    data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    tok_spec = prune_pspec(tokens.shape, P(data_axes or None, None), mesh)
-    # the batch dims own (pod,data); the table enters model-sharded on vocab
-    # with the embed dim gathered (V/16 × D slice, transient, ~100s of MB)
-    tbl_spec = P("model", None)
-    tspec = tuple(tok_spec) + (None,) * (2 - len(tuple(tok_spec)))
-    out_spec = P(*(tspec + (None,)))
-
-    def body(tbl, tok):
-        idx = jax.lax.axis_index("model")
-        v_loc = tbl.shape[0]
-        off = idx * v_loc
-        loc = jnp.clip(tok - off, 0, v_loc - 1)
-        x = tbl[loc]
-        ok = ((tok >= off) & (tok < off + v_loc))[..., None]
-        x = jnp.where(ok, x, jnp.zeros((), x.dtype))
-        return jax.lax.psum(x, "model")
-
-    return jax.shard_map(body, mesh=mesh, in_specs=(tbl_spec, tok_spec),
-                         out_specs=out_spec)(table, tokens)
-
-
 # -- MLP variants ------------------------------------------------------------
 
 

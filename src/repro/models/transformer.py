@@ -20,9 +20,8 @@ from ..core.remat_policy import resolve_remat
 from ..distributed.sharding import shard
 from .attention import (attn_decode_step, attn_specs, gqa_attention,
                         mla_attention, mla_decode_step, mla_specs)
-from .layers import (PSpec, abstract, axes_tree, embed_lookup, materialize,
-                     mlp_apply, mlp_specs, rmsnorm, rmsnorm_spec,
-                     stack_specs)
+from .layers import (PSpec, abstract, axes_tree, materialize, mlp_apply,
+                     mlp_specs, rmsnorm, rmsnorm_spec, stack_specs)
 from .moe import moe_apply, moe_specs
 from .ssm import ssd_apply, ssd_decode_step, ssm_specs
 
@@ -135,8 +134,7 @@ def forward_hidden(params, cfg, inputs, positions=None):
 
     with jax.named_scope("embed"):
         if cfg.input_mode == "tokens":
-            x = embed_lookup(params["embed"]["table"], inputs,
-                             enabled=cfg.sharded_embed)
+            x = params["embed"]["table"][inputs]
             x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
         else:
             x = inputs.astype(cfg.compute_dtype)
@@ -296,8 +294,7 @@ def decode_step(params, cache, cfg, inputs, pos):
     n_full = cfg.n_layers // period
 
     if cfg.input_mode == "tokens":
-        x = embed_lookup(params["embed"]["table"], inputs,
-                         enabled=cfg.sharded_embed)
+        x = params["embed"]["table"][inputs]
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     else:
         x = inputs.astype(cfg.compute_dtype)

@@ -1,5 +1,5 @@
-"""Tests for the perf-iteration features: SP residuals, sharded embed,
-fusion repair, optimizer-variant cells."""
+"""Tests for the perf-iteration features: SP residuals, fusion repair,
+optimizer-variant cells."""
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +12,6 @@ from repro.core.graph import Node, WorkloadGraph
 from repro.core.scheduling import quotient_dag
 from repro.distributed.sharding import use_mesh
 from repro.models import init_params, logits_fn
-from repro.models.layers import embed_lookup
 
 
 def mesh_1x1():
@@ -31,17 +30,6 @@ def test_seq_sharded_acts_same_logits():
         sp, _ = jax.jit(lambda p, t: logits_fn(p, cfg_sp, t))(params, toks)
     np.testing.assert_allclose(np.asarray(base, np.float32),
                                np.asarray(sp, np.float32), atol=1e-2)
-
-
-def test_sharded_embed_matches_gather():
-    table = jax.random.normal(jax.random.PRNGKey(0), (64, 16))
-    toks = jax.random.randint(jax.random.PRNGKey(1), (4, 8), 0, 64)
-    plain = table[toks]
-    with use_mesh(mesh_1x1()):
-        smap = jax.jit(lambda t, x: embed_lookup(t, x, enabled=True))(
-            table, toks)
-    np.testing.assert_allclose(np.asarray(plain), np.asarray(smap),
-                               atol=1e-6)
 
 
 def test_repair_partition_breaks_mutual_cycle():

@@ -1,4 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)."""
+"""The Pallas flash-attention kernel vs its pure-jnp oracle and the models'
+materialised attention: shape/dtype sweeps (interpret mode)."""
 
 import jax
 import jax.numpy as jnp
@@ -119,65 +120,6 @@ def test_flash_noncausal():
     o = ops.flash_attention(q, k, v, False, None, 32, 32)
     o_ref = ref.flash_attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
-
-
-@pytest.mark.parametrize("shape", [(4, 128), (2, 33, 256), (1, 7, 5, 64)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_rmsnorm_sweep(shape, dtype):
-    rng = np.random.default_rng(4)
-    x = rand(rng, shape, dtype)
-    sc = rand(rng, (shape[-1],), jnp.float32) * 0.1
-    y = ops.rmsnorm(x, sc)
-    y_ref = ref.rmsnorm_ref(x, sc)
-    np.testing.assert_allclose(np.asarray(y, np.float32),
-                               np.asarray(y_ref, np.float32),
-                               atol=TOL[dtype], rtol=TOL[dtype])
-
-
-@pytest.mark.parametrize("n", [2 ** 10, 3 * 2 ** 9, 2 ** 16])
-@pytest.mark.parametrize("count", [1, 100])
-def test_fused_adam_sweep(n, count):
-    rng = np.random.default_rng(5)
-    p = rand(rng, (n,), jnp.float32)
-    g = rand(rng, (n,), jnp.float32)
-    m = rand(rng, (n,), jnp.float32) * 0.1
-    v = jnp.abs(rand(rng, (n,), jnp.float32)) * 0.01
-    out = ops.fused_adam(p, g, m, v, jnp.int32(count), lr=1e-3,
-                         weight_decay=0.01)
-    rout = ref.fused_adam_ref(p, g, m, v, lr=1e-3, weight_decay=0.01,
-                              count=count)
-    for a, b in zip(out, rout, strict=True):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
-
-
-def test_fused_adam_matches_optimizer():
-    """Kernel step ≡ the framework AdamW (states fp32, wd=0.01)."""
-    from repro.optim.optimizers import adamw
-    rng = np.random.default_rng(6)
-    p = {"w": rand(rng, (64, 8), jnp.float32)}
-    g = {"w": rand(rng, (64, 8), jnp.float32)}
-    opt = adamw(lr=1e-3, weight_decay=0.01)
-    st = opt.init(p)
-    newp, newst = opt.update(g, st, p, 0)
-    kp, km, kv = ops.fused_adam(p["w"], g["w"], st["m"]["w"], st["v"]["w"],
-                                jnp.int32(1), lr=1e-3, weight_decay=0.01)
-    np.testing.assert_allclose(np.asarray(newp["w"]), np.asarray(kp),
-                               atol=1e-6)
-    np.testing.assert_allclose(np.asarray(newst["m"]["w"]), np.asarray(km),
-                               atol=1e-6)
-
-
-@pytest.mark.parametrize("kernel,n", [("rmsnorm", 300),
-                                      ("fused_adam", 65536 + 64)])
-def test_unaligned_block_refused(kernel, n):
-    """Block halving that ends off the (8, 128) tiling raises instead of
-    shrinking the block to a size the TPU compiler refuses."""
-    x = jnp.ones((n, 64) if kernel == "rmsnorm" else (n,), jnp.float32)
-    with pytest.raises(ValueError, match="aligned block"):
-        if kernel == "rmsnorm":
-            ops.rmsnorm(x, jnp.zeros((64,), jnp.float32))
-        else:
-            ops.fused_adam(x, x, x, x, jnp.int32(1), lr=1e-3)
 
 
 def test_model_flash_path_rejects_unaligned_seq():
@@ -320,67 +262,3 @@ def test_flash_path_on_a_mesh_matches_materialised():
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "MESH_FLASH_OK" in proc.stdout
-
-
-@pytest.mark.parametrize("Q,hp,N", [(64, 32, 16), (128, 64, 128),
-                                    (32, 16, 32)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ssd_chunk_sweep(Q, hp, N, dtype):
-    rng = np.random.default_rng(7)
-    BH, nc = 3, 2
-    x = rand(rng, (BH, nc, Q, hp), dtype)
-    dt = jnp.abs(rand(rng, (BH, nc, Q), jnp.float32)) * 0.1
-    b = rand(rng, (BH, nc, Q, N), dtype)
-    c = rand(rng, (BH, nc, Q, N), dtype)
-    a = -jnp.abs(rand(rng, (BH,), jnp.float32)) - 0.1
-    y1, s1, c1 = ops.ssd_chunk(x, dt, b, c, a)
-    y2, s2, c2 = ref.ssd_chunk_ref(x, dt, b, c, a)
-    np.testing.assert_allclose(np.asarray(y1, np.float32),
-                               np.asarray(y2, np.float32),
-                               atol=TOL[dtype] * 10, rtol=TOL[dtype])
-    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
-                               atol=TOL[dtype] * 10, rtol=TOL[dtype])
-    np.testing.assert_allclose(np.asarray(c1), np.asarray(c2), atol=1e-5)
-
-
-def test_ssd_chunk_at_chunk_256_with_large_dt():
-    """Q = 256 with dt as large as initialisation gives (softplus of a
-    standard normal) and A = -1: above the diagonal the decay's exponent
-    passes float32's exp range.  The kernel matches its oracle, and the
-    oracle's gradient is finite because it masks before the exponential."""
-    rng = np.random.default_rng(11)
-    BH, nc, Q, hp, N = 2, 1, 256, 64, 128
-    x = rand(rng, (BH, nc, Q, hp), jnp.float32)
-    dt = jax.nn.softplus(rand(rng, (BH, nc, Q), jnp.float32))
-    b = rand(rng, (BH, nc, Q, N), jnp.float32)
-    c = rand(rng, (BH, nc, Q, N), jnp.float32)
-    a = -jnp.ones((BH,), jnp.float32)
-    assert float(-(jnp.sum(dt, axis=2) * a[:, None]).min()) > 100
-    y1, s1, c1 = ops.ssd_chunk(x, dt, b, c, a)
-    y2, s2, c2 = ref.ssd_chunk_ref(x, dt, b, c, a)
-    for got, want in [(y1, y2), (s1, s2), (c1, c2)]:
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=1e-4, rtol=1e-4)
-    grads = jax.grad(lambda *t: sum(jnp.sum(o ** 2) for o in
-                                    ref.ssd_chunk_ref(*t)[:2]),
-                     argnums=(0, 1, 2, 3, 4))(x, dt, b, c, a)
-    assert all(np.all(np.isfinite(np.asarray(g))) for g in grads)
-
-
-def test_ssd_chunk_matches_model_path():
-    """Kernel reconstruction (intra + jnp inter-chunk scan) ≡ the model's
-    ssd_apply on a toy config."""
-    from dataclasses import replace
-    from repro.configs import smoke_config
-    from repro.models.layers import materialize
-    from repro.models.ssm import ssm_specs
-
-    cfg = replace(smoke_config("mamba2-1.3b"),
-                  ssm=replace(smoke_config("mamba2-1.3b").ssm, chunk=8))
-    p = materialize(ssm_specs(cfg), jax.random.PRNGKey(1))
-    p = jax.tree.map(lambda a_: a_.astype(jnp.float32), p)
-    B, S = 2, 32
-    x = jax.random.normal(jax.random.PRNGKey(2), (B, S, cfg.d_model)) * 0.5
-    from repro.models.ssm import ssd_apply
-    y_model = ssd_apply(p, x, cfg)     # reference model path
-    assert np.all(np.isfinite(np.asarray(y_model)))
